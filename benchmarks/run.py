@@ -286,12 +286,13 @@ import json, tempfile, time
 import os.path as osp
 import numpy as np
 import jax
+from repro.runtime.compile_cache import enable_compile_cache
 # Persistent compile cache: each executor slot jits its own step (the
 # prolog memo is keyed per device), so fake devices 1..3 would recompile
 # the identical HLO (~0.4 s each).  The cache deserializes device 0's
 # executable instead — the sweep measures scheduling and pipelining, not
 # XLA compile times.
-jax.config.update("jax_compilation_cache_dir", tempfile.mkdtemp())
+enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 from repro.api import ExecSpec, GridSpec, Study
@@ -388,10 +389,11 @@ def _executor_child_rows() -> dict:
         capture_output=True, text=True, timeout=1800, env=env,
     )
     if proc.returncode != 0:
-        emit("executor_sweep_failed", 0.0, proc.stderr.strip()[-120:].replace(",", ";"))
-        _MD_ROWS = {"executor": [], "pipeline": []}
-    else:
-        _MD_ROWS = json.loads(proc.stdout.strip().splitlines()[-1])
+        raise SystemExit(
+            f"executor sweep child failed (exit {proc.returncode}):\n"
+            f"{proc.stderr.strip()[-3000:]}"
+        )
+    _MD_ROWS = json.loads(proc.stdout.strip().splitlines()[-1])
     return _MD_ROWS
 
 

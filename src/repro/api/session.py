@@ -778,6 +778,9 @@ class MultiDeviceExecutor:
         host = self.backend_opts.get("host_id")
         self._label_prefix = f"{host}/" if (backend != "threads" and host) else ""
         self._worker_stats: dict = {}
+        # slot label -> the devices its first step's outputs live on (the
+        # placement proof a multi-chip run prints)
+        self._slot_devices: dict[str, list[str]] = {}
         self._autotune: dict = {
             "enabled": self.autotune_lease,
             "initial_lease": lease_batches,
@@ -806,6 +809,7 @@ class MultiDeviceExecutor:
             "workers": {
                 w: dataclasses.asdict(st) for w, st in sorted(self._worker_stats.items())
             },
+            "slot_devices": dict(sorted(self._slot_devices.items())),
         }
         if self.backend != "threads":
             out["host_id"] = self.backend_opts.get("host_id")
@@ -971,6 +975,11 @@ class MultiDeviceExecutor:
                                 staged_args(nxt)
                         jax.block_until_ready(out)
                         step_s = time.perf_counter() - t0
+                        if label not in self._slot_devices:
+                            self._slot_devices[label] = sorted(
+                                {str(d) for a in jax.tree.leaves(out)
+                                 for d in a.devices()}
+                            )
                         emit = make_emit(
                             hb, out, blk, batch, step_s, decode_s, stage_s,
                             h2d_bytes,

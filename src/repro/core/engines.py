@@ -37,7 +37,7 @@ from repro.core.association import (
     sparse_epilogue_outputs,
     standardize_genotype_batch,
 )
-from repro.runtime.compat import shard_map
+from repro.kernels import pallas_interpret
 from repro.runtime.prefetch import MarkerBatch, TraitBlock
 from repro.runtime.sharding import batch_axes, gwas_shardings
 
@@ -625,6 +625,8 @@ def build_dense_step(
     # standardized arrays don't stay pinned on device for the lifetime of a
     # cached plan.
     step.reset = lambda: memo.update(g=None, out=None)
+    # The two jitted programs, for ahead-of-time lowering (compile checks).
+    step.prolog, step.cell = prolog_j, cell_j
     return step
 
 
@@ -666,7 +668,7 @@ def build_fused_step(
     if packed_input and mesh is not None:
         raise ValueError("packed_input requires mesh=None (see resolve_genotype_staging)")
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     dof = options.dof(n_samples, n_covariates)
     sparse = _resolve_sparse(
         sparse_epilogue, mesh, options, hit_threshold, dof, hit_capacity
@@ -688,7 +690,7 @@ def build_fused_step(
 
     if mesh is not None:
         dp = batch_axes(mesh)
-        kernel_fn = shard_map(
+        kernel_fn = jax.shard_map(
             kernel_local,
             mesh=mesh,
             in_specs=(P(dp, None), P(dp, None), P(dp, None), P(None, "model")),
@@ -930,6 +932,7 @@ def build_lmm_step(
 
     # See build_dense_step: drop the pinned last batch at executor teardown.
     step.reset = lambda: memo.update(g=None, out=None)
+    step.prolog, step.cell = prolog_j, cell_j
     return step
 
 

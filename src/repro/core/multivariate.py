@@ -51,7 +51,7 @@ def whiten_panel(y_std: jax.Array, *, eig_floor: float = 1e-6) -> tuple[jax.Arra
     """
     y = jnp.asarray(y_std, jnp.float32)
     n = y.shape[0]
-    corr = (y.T @ y) / n
+    corr = jnp.matmul(y.T, y, precision=jax.lax.Precision.HIGHEST) / n
     lam, vec = jnp.linalg.eigh(corr)
     lam = lam[::-1]
     vec = vec[:, ::-1]
@@ -71,7 +71,7 @@ def omnibus_chi2(
     ``r_w = r @ W`` decorrelates the traits (pass ``whitening=None`` only if
     the panel was already whitened)."""
     if whitening is not None:
-        r_tile = r_tile @ whitening
+        r_tile = jnp.matmul(r_tile, whitening, precision=jax.lax.Precision.HIGHEST)
     s = jnp.asarray(n_samples, jnp.float32) * jnp.sum(jnp.square(r_tile), axis=-1)
     nlp = _stats.neglog10_sf_chi2(s, n_traits_eff)
     return s, nlp

@@ -74,9 +74,15 @@ def covariate_basis(
     return q * keep[None, :].astype(q.dtype)
 
 
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    # f32 products: the TPU's default contracts f32 in one bf16 pass, which
+    # leaves bf16-sized error in every residual (PERF.md).
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _project_out(x: jax.Array, q: jax.Array) -> jax.Array:
     """``(I - Q Q^T) x`` without materializing the N x N projector."""
-    return x - q @ (q.T @ x)
+    return x - _dot(q, _dot(q.T, x))
 
 
 def residualize_and_standardize(
@@ -117,7 +123,7 @@ def residualize_genotypes(g_std: jax.Array, q: jax.Array, *, var_tol: float = 1e
     ``tests/test_residualize.py`` against a direct lstsq fit).
     """
     g = jnp.asarray(g_std, jnp.float32)
-    g_res = (g - (g @ q) @ q.T)
+    g_res = g - _dot(_dot(g, q), q.T)
     var = jnp.mean(jnp.square(g_res), axis=1)
     valid = var > var_tol
     inv_std = jnp.where(valid, jax.lax.rsqrt(jnp.maximum(var, var_tol)), 0.0)

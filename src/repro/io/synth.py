@@ -179,35 +179,43 @@ def make_structured_cohort(
     )
 
 
-def write_cohort_files(cohort: SyntheticCohort, stem: str) -> dict[str, str]:
+def write_cohort_files(
+    cohort: SyntheticCohort, stem: str, *, bgen: bool = True
+) -> dict[str, str]:
     """Materialize a cohort as on-disk PLINK + BGEN + tables (for IO tests
-    and the quickstart example).  Returns the path map."""
+    and the quickstart example).  Returns the path map.  ``bgen=False``
+    skips the BGEN copy, whose per-variant compression dominates the write
+    at biobank sizes."""
     from repro.io.bgen import write_bgen
     from repro.io.plink import write_plink
 
     paths: dict[str, str] = {}
     paths["bed"] = write_plink(stem, cohort.dosages, sample_ids=cohort.sample_ids)
-    paths["bgen"] = write_bgen(
-        stem + ".bgen",
-        cohort.dosages,
-        sample_ids=cohort.sample_ids,
-        rsids=cohort.marker_ids,
-    )
-    pheno_path = stem + ".pheno.tsv"
-    with open(pheno_path, "w") as f:
-        f.write("FID\tIID\t" + "\t".join(f"trait{j}" for j in range(cohort.phenotypes.shape[1])) + "\n")
-        for i, sid in enumerate(cohort.sample_ids):
-            vals = "\t".join(f"{v:.6g}" for v in cohort.phenotypes[i])
-            f.write(f"{sid}\t{sid}\t{vals}\n")
-    paths["pheno"] = pheno_path
-    cov_path = stem + ".cov.tsv"
-    with open(cov_path, "w") as f:
-        f.write("FID\tIID\t" + "\t".join(f"cov{j}" for j in range(cohort.covariates.shape[1])) + "\n")
-        for i, sid in enumerate(cohort.sample_ids):
-            vals = "\t".join(f"{v:.6g}" for v in cohort.covariates[i])
-            f.write(f"{sid}\t{sid}\t{vals}\n")
-    paths["cov"] = cov_path
+    if bgen:
+        paths["bgen"] = write_bgen(
+            stem + ".bgen",
+            cohort.dosages,
+            sample_ids=cohort.sample_ids,
+            rsids=cohort.marker_ids,
+        )
+    paths["pheno"] = _write_table(stem + ".pheno.tsv", cohort.sample_ids,
+                                  "trait", cohort.phenotypes)
+    paths["cov"] = _write_table(stem + ".cov.tsv", cohort.sample_ids,
+                                "cov", cohort.covariates)
     return paths
+
+
+def _write_table(path: str, sample_ids: list[str], prefix: str,
+                 values: np.ndarray) -> str:
+    """FID/IID table with 6-significant-digit values (one printf-style
+    format per row: the per-value formatting dominates at biobank size)."""
+    cols = values.shape[1]
+    row = "\t".join(["%.6g"] * cols)
+    with open(path, "w") as f:
+        f.write("FID\tIID\t" + "\t".join(f"{prefix}{j}" for j in range(cols)) + "\n")
+        for sid, vals in zip(sample_ids, values.tolist()):
+            f.write(f"{sid}\t{sid}\t" + row % tuple(vals) + "\n")
+    return path
 
 
 def write_split_plink(

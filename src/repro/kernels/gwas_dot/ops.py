@@ -2,8 +2,9 @@
 
 Owns everything the kernel does not: tile-local packing, marker-stat
 computation from raw 2-bit counts, padding to block multiples, un-padding,
-and the interpret-mode fallback (CPU containers validate the kernel body in
-interpret mode; on TPU the same call lowers to Mosaic).
+and the backend's interpret choice (``repro.kernels.pallas_interpret``: the
+CPU backend validates the kernel body in interpret mode; on TPU the same
+call lowers to Mosaic).
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import pallas_interpret
 from repro.kernels.gwas_dot.gwas_dot import build_gwas_dot
 
 __all__ = [
@@ -245,7 +247,7 @@ def gwas_dot(
     ``P`` are padded to block multiples internally and sliced back.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     m_true = packed_tiled.shape[0]
     p_true = y.shape[1]
     n_pad = packed_tiled.shape[1] * 4

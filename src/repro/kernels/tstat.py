@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_interpret
+
 __all__ = ["screen_compact", "tstat", "tstat_ref"]
 
 
@@ -50,7 +52,7 @@ def tstat(
 ) -> jax.Array:
     """Elementwise ``T = R * sqrt(dof / (1 - R^2))`` over an ``(M, P)`` tile."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     r = jnp.asarray(r, jnp.float32)
     m_true, p_true = r.shape
     pad_m = (-m_true) % block_m
@@ -60,6 +62,9 @@ def tstat(
         r_pad, dof=float(dof), block_m=block_m, block_p=block_p, interpret=bool(interpret)
     )
     return t[:m_true, :p_true]
+
+
+_COUNT_TILE = (8, 128)  # smallest int32 block Mosaic accepts
 
 
 def _screen_kernel(r_ref, t_ref, mask_ref, count_ref, *, dof: float,
@@ -72,7 +77,10 @@ def _screen_kernel(r_ref, t_ref, mask_ref, count_ref, *, dof: float,
     t_ref[...] = t
     keep = t * t >= t2_screen
     mask_ref[...] = keep.astype(jnp.int8)
-    count_ref[0, 0] = jnp.sum(keep).astype(jnp.int32)
+    # The block's survivor count, broadcast over one (8, 128) int32 tile:
+    # Mosaic needs the last two block dims to be multiples of (8, 128), so a
+    # per-block scalar output is not expressible; the wrapper reads one lane.
+    count_ref[...] = jnp.full(count_ref.shape, jnp.sum(keep.astype(jnp.int32)))
 
 
 @functools.partial(
@@ -90,12 +98,12 @@ def _screen_padded(r, *, dof, t2_screen, block_m, block_p, interpret):
         out_specs=[
             pl.BlockSpec((block_m, block_p), lambda i, j: (i, j)),
             pl.BlockSpec((block_m, block_p), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec(_COUNT_TILE, lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, p), jnp.float32),
             jax.ShapeDtypeStruct((m, p), jnp.int8),
-            jax.ShapeDtypeStruct((gm, gp), jnp.int32),
+            jax.ShapeDtypeStruct((gm * _COUNT_TILE[0], gp * _COUNT_TILE[1]), jnp.int32),
         ],
         interpret=interpret,
     )(r)
@@ -126,7 +134,7 @@ def screen_compact(
     must never survive the screen.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     r = jnp.asarray(r, jnp.float32)
     m_true, p_true = r.shape
     pad_m = (-m_true) % block_m
@@ -138,4 +146,5 @@ def screen_compact(
     )
     keep = mask[:m_true, :p_true].ravel() != 0
     idx = jnp.nonzero(keep, size=int(capacity), fill_value=-1)[0].astype(jnp.int32)
-    return t[:m_true, :p_true], idx, jnp.sum(counts).astype(jnp.int32)
+    per_block = counts[:: _COUNT_TILE[0], :: _COUNT_TILE[1]]
+    return t[:m_true, :p_true], idx, jnp.sum(per_block).astype(jnp.int32)

@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core.association import AssocOptions
 from repro.core.engines import available_engines
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.workqueue import available_backends
 
 SUBCOMMANDS = ("scan", "grm", "merge", "report", "serve")
@@ -590,6 +591,7 @@ def cmd_serve(argv) -> None:
 
 def main(argv=None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
+    enable_compile_cache()
     try:
         if argv and argv[0] in SUBCOMMANDS:
             cmd, rest = argv[0], argv[1:]

@@ -7,10 +7,22 @@ process sets ``xla_force_host_platform_device_count``).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "POD_CHIPS", "describe"]
+__all__ = ["make_mesh", "make_production_mesh", "POD_CHIPS", "describe"]
 
 POD_CHIPS = 256  # 16 x 16 TPU v5e pod slice
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], **kwargs):
+    """``jax.make_mesh`` with GSPMD (``AxisType.Auto``) axes.
+
+    The scan steps declare in/out shardings and let the partitioner place
+    everything between; ``jax.make_mesh`` defaults to explicit axes, under
+    which their per-trait gathers (``take_along_axis`` over a marker-sharded
+    tile) have no resolvable output sharding and are refused.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes), **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     between pods is too slow for TP), which the sharding rules encode."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def describe(mesh) -> str:

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.sharding_ctx import constrain
-from repro.runtime.compat import token_prefix_sum
 
 __all__ = ["init_moe_params", "moe_layer"]
 
@@ -74,10 +73,9 @@ def moe_layer(cfg: ModelConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.A
         gate = jnp.max(remaining, axis=-1)                      # (T,)
         expert = jnp.argmax(remaining, axis=-1)                 # (T,)
         onehot = jax.nn.one_hot(expert, e, dtype=jnp.float32)   # (T, E)
-        # Prefix sum over the token axis.  The token axis may be GSPMD-
-        # sharded here, so this must go through the partitioner-safe helper
-        # (associative_scan is miscompiled on sharded axes by old jax).
-        csum = token_prefix_sum(onehot, axis=0)
+        # Prefix sum over the (possibly GSPMD-sharded) token axis: the
+        # log-depth scan partitions correctly on the installed jax.
+        csum = jax.lax.associative_scan(jnp.add, onehot, axis=0)
         pos = (csum - 1.0) + expert_fill[None, :].astype(jnp.float32)
         pos_tok = jnp.sum(pos * onehot, axis=-1)                # (T,)
         keep = pos_tok < cap
@@ -144,9 +142,9 @@ def _moe_local(cfg: ModelConfig, p: dict, xt: jax.Array, n_local_experts: int, a
         gate = jnp.max(remaining, axis=-1)
         expert = jnp.argmax(remaining, axis=-1)
         onehot = jax.nn.one_hot(expert, e, dtype=jnp.float32)
-        # Token axis is device-local inside shard_map; the helper still
-        # keeps the lowering consistent with the GSPMD path above.
-        csum = token_prefix_sum(onehot, axis=0)
+        # Token axis is device-local inside shard_map; same scan as the
+        # GSPMD path above.
+        csum = jax.lax.associative_scan(jnp.add, onehot, axis=0)
         pos_tok = jnp.sum((csum - 1.0 + expert_fill[None].astype(jnp.float32)) * onehot, -1)
         local = (expert >= first) & (expert < first + n_local_experts)
         keep = (pos_tok < cap) & local
@@ -179,7 +177,6 @@ def moe_layer_manual(cfg: ModelConfig, p: dict, x: jax.Array, mesh) -> tuple[jax
     """Manual expert-parallel MoE via shard_map (moe_impl='manual')."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.compat import shard_map
     from repro.runtime.sharding import batch_axes
 
     moe = cfg.moe
@@ -205,7 +202,7 @@ def moe_layer_manual(cfg: ModelConfig, p: dict, x: jax.Array, mesh) -> tuple[jax
     }
     if "dense" in p:
         p_specs["dense"] = jax.tree.map(lambda _: P(), p["dense"])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(p_specs, P(dp, None, None)),

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.runtime.compat import shard_map
-
 __all__ = ["compressed_psum", "build_compressed_grad_sync"]
 
 
@@ -53,7 +51,7 @@ def build_compressed_grad_sync(mesh: Mesh, grads_like: Any, *, bits: int = 8, ax
         return jax.tree.map(one, grads)
 
     spec = P()  # grads replicated over the data axes after the sum
-    return shard_map(
+    return jax.shard_map(
         local_sync,
         mesh=mesh,
         in_specs=jax.tree.map(lambda _: spec, grads_like),

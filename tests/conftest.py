@@ -11,8 +11,9 @@ import pytest
 from repro.io import synth
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# Tooling byproducts that may legitimately appear in the checkout.
-_TREE_IGNORED = {".pytest_cache", "__pycache__", ".hypothesis"}
+# Tooling byproducts that may legitimately appear in the checkout (the
+# last is the compile cache ``repro.launch.gwas.main`` turns on).
+_TREE_IGNORED = {".pytest_cache", "__pycache__", ".hypothesis", ".jax_cache"}
 
 
 @pytest.fixture(autouse=True)

@@ -449,6 +449,7 @@ _CHILD = textwrap.dedent(
         out[f"{name}_identical"] = multi == ref
         info = session.executor_info
         out[f"{name}_workers"] = len(info["workers"])
+        out[f"{name}_slot_devices"] = info["slot_devices"]
         out[f"{name}_devices_used"] = len(
             session.metrics.summary()["per_device"]
         )
@@ -520,6 +521,16 @@ def test_multi_device_bitwise_identical(child_results, engine):
     assert child_results[f"{engine}_identical"] is True
     assert child_results[f"{engine}_workers"] >= 2
     assert child_results[f"{engine}_devices_used"] >= 2
+
+
+@pytest.mark.parametrize("engine", ["dense", "dense_exact", "fused", "lmm_loco"])
+def test_slot_outputs_live_on_their_own_device(child_results, engine):
+    """Every slot that computed a cell holds its step outputs on exactly one
+    device, and no two slots share one: placement is explicit per slot."""
+    placed = child_results[f"{engine}_slot_devices"]
+    assert len(placed) >= 2
+    assert all(len(devs) == 1 for devs in placed.values())
+    assert len({devs[0] for devs in placed.values()}) == len(placed)
 
 
 def test_trait_major_placement_bitwise_identical(child_results):
