@@ -11,8 +11,8 @@
                     block, not the panel; statistics bitwise-identical;
                     warm-measured — see the §10 compile-time note)
     executor      — multi-device grid executor sweep (fake CPU devices in a
-                    subprocess): device count x placement, per-device
-                    utilization from the session metrics, bitwise identity
+                    subprocess): device count x placement, the scheduler's
+                    busy/(busy+wait) utilization, bitwise identity
     pipeline      — per-slot pipelining before/after (§15): unpipelined vs
                     prefetched/double-buffered workers at 2 and 4 devices,
                     decode/stage shares of step time
@@ -329,12 +329,9 @@ for devices, placement in [(1, "marker-major"), (2, "marker-major"),
     run(devices, placement, 1, True)   # warm page + compile caches
     dt, key, m, info = run(devices, placement, 1, True)
     ref = key if ref is None else ref
-    # Two utilization views: the scheduler's busy/(busy+wait) accounting
-    # (time holding >=1 claimed item vs empty-handed — DESIGN.md §15) and
-    # the per-cell busy_s/wall from the metrics block.  On fake devices
-    # timesharing one core the latter is distorted (concurrent steps
-    # inflate each other's wall, so it can exceed 1); the scheduler view
-    # is the meaningful one here.
+    # Utilization is the scheduler's busy/(busy+wait) accounting (time
+    # holding >=1 claimed item vs empty-handed — DESIGN.md §15); None when
+    # the scheduler reports no workers.
     workers = info.get("workers") or {}
     shares = [
         w["busy_s"] / max(w["busy_s"] + w["wait_s"], 1e-9)
@@ -344,13 +341,7 @@ for devices, placement in [(1, "marker-major"), (2, "marker-major"),
         "devices": devices, "placement": placement, "wall_s": round(dt, 3),
         "markers_per_s": m["markers_per_s"],
         "trait_markers_per_s": m["trait_markers_per_s"],
-        "mean_utilization": round(sum(shares) / len(shares), 3) if shares
-        else round(
-            sum(v["utilization"] for v in m["per_device"].values())
-            / max(len(m["per_device"]), 1), 3),
-        "cell_util": round(
-            sum(v["utilization"] for v in m["per_device"].values())
-            / max(len(m["per_device"]), 1), 3),
+        "mean_utilization": round(sum(shares) / len(shares), 3) if shares else None,
         "final_lease": (info.get("autotune") or {}).get("final_lease"),
         "identical_to_serial": key == ref,
     })
@@ -402,7 +393,7 @@ def bench_executor() -> None:
     devices in a subprocess (the device count is fixed at process start).
     Fake devices timeshare ONE physical CPU, so wall time here measures
     scheduling/staging overhead, not speedup — the rows that matter are
-    per-device utilization (the executor keeps slots busy), the session
+    the scheduler's utilization (the executor keeps slots busy), the session
     metrics throughput, and ``identical=True`` (bitwise identity across
     device counts and placements, the §12 contract).  Each config is run
     twice and the warm run reported (first-touch page-cache and compile-

@@ -10,6 +10,12 @@ into the session's ``ScanMetrics``.  Three surfaces read it:
     summary    ``summary.json``'s ``metrics`` block via ``summary()``
     BENCH      ``benchmarks/run.py``'s executor section rows
 
+The executors time their layers through ``span``: one ``with`` opens a
+``jax.profiler.TraceAnnotation`` named ``gwas.<name>`` (inert unless a
+profiler session is on) and folds the same ``perf_counter`` duration into
+``ScanMetrics``' span totals, so a profiler trace and the host totals
+read one clock and one boundary.  ``SPANS`` names every layer.
+
 Timing is observational only: recording happens after the cell's arrays
 are materialized (the commit/writer path forces that synchronization
 anyway), so the hook never adds device syncs of its own.  Replayed
@@ -18,10 +24,60 @@ one ``np.load``, not a device step.
 """
 from __future__ import annotations
 
+import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass
+from typing import Iterator
 
-__all__ = ["CellTiming", "ScanMetrics"]
+import jax
+
+__all__ = ["CellTiming", "ScanMetrics", "SPANS", "SPAN_PREFIX", "span"]
+
+SPAN_PREFIX = "gwas."
+# Every host span the scan opens, in pipeline order (DESIGN.md §18):
+#   decode      ``prepare_batch`` on a decode worker
+#   wait_input  the compute thread waiting for its next decoded, staged batch
+#   stage       the H2D staging copy of one batch
+#   panel       a panel block staged, on the compute thread or the look-ahead
+#   dispatch    the step call (with its memoised repack or prolog)
+#   fence       ``block_until_ready`` on the step's outputs
+#   extract     payload materialization; holds ``pull`` (one D2H pull) and
+#               ``refine`` (one ``refine_neglog10p`` call)
+#   sinks       checkpoint commit and ``ScanMetrics.record`` in ``events()``;
+#               carries the cell's counters as arguments
+#   claim       a scheduler claim (multi-device)
+#   tail        one task of a slot's tail thread (multi-device)
+#   write       ``ResultWriter.write``
+SPANS = ("decode", "wait_input", "stage", "panel", "dispatch", "fence",
+         "extract", "pull", "refine", "sinks", "claim", "tail", "write")
+
+
+class SpanTime:
+    """What a ``span`` measured: its host seconds, set on exit."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+
+
+@contextmanager
+def span(name: str, slot: str = "-", metrics: "ScanMetrics | None" = None,
+         **args) -> Iterator[SpanTime]:
+    """Time one layer: a profiler span ``gwas.<name>`` carrying ``slot``
+    (``serial``, ``dev<i>``) and ``args``, whose ``perf_counter`` duration
+    is folded into ``metrics`` (thread-safe) and left on the yielded
+    ``SpanTime``."""
+    got = SpanTime()
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(SPAN_PREFIX + name, slot=slot, **args):
+        try:
+            yield got
+        finally:
+            got.seconds = time.perf_counter() - t0
+            if metrics is not None:
+                metrics.fold_span(name, got.seconds)
 
 
 @dataclass(frozen=True)
@@ -58,12 +114,28 @@ class CellTiming:
     # The observable packed genotype staging (DESIGN.md §17) drives down
     # ~16x: ceil(N/4) packed bytes/marker vs 4N decoded float32.
     h2d_bytes: int = 0
+    # Extraction counters (DESIGN.md §13): executable launches of the
+    # canonical refine and the lanes they evaluated, against the hit rows
+    # emitted (useful over attempted); the cell's screened-lane count and
+    # whether it overflowed the compact buffer to the host fallback; and
+    # the refine launches that ran on a device other than the slot's own.
+    refine_launches: int = 0
+    refine_lanes: int = 0
+    hits: int = 0
+    screen_count: int = 0
+    overflowed: bool = False
+    foreign_refines: int = 0
+
+
+# CellTiming counters ScanMetrics totals over live cells.
+COUNTERS = ("refine_launches", "refine_lanes", "hits", "screen_count",
+            "overflowed", "foreign_refines")
 
 
 class ScanMetrics:
-    """Fold of a session's ``CellTiming`` rows, cheap enough to keep always
-    on.  ``wall_s`` is the stream's wall clock (``start()`` .. ``finish()``),
-    against which per-device busy time yields utilization."""
+    """Fold of a session's ``CellTiming`` rows and ``span`` durations,
+    cheap enough to keep always on.  ``wall_s`` is the stream's wall clock
+    (``start()`` .. ``finish()``)."""
 
     def __init__(self, n_cells_total: int = 0):
         self.n_cells_total = n_cells_total
@@ -82,7 +154,11 @@ class ScanMetrics:
         self._decode_s = 0.0
         self._stage_s = 0.0
         self._h2d_bytes = 0
+        self._counters = dict.fromkeys(COUNTERS, 0)
         self._per_device: dict[str, dict] = {}     # label -> cells/busy_s/...
+        # span name -> [seconds, count]; folded from any thread
+        self._spans: dict[str, list] = {}
+        self._span_lock = threading.Lock()
         # Serve-mode observability (repro.serve): per-request wall-clock
         # latencies (requests are few relative to cells, so retaining them
         # for exact percentiles is cheap), a queue-depth gauge, and cache
@@ -111,6 +187,8 @@ class ScanMetrics:
             self._decode_s += row.decode_s
             self._stage_s += row.stage_s
             self._h2d_bytes += row.h2d_bytes
+            for k in COUNTERS:
+                self._counters[k] += int(getattr(row, k))
             d = self._per_device.setdefault(
                 row.device,
                 {"cells": 0, "busy_s": 0.0, "decode_s": 0.0, "stage_s": 0.0,
@@ -121,6 +199,13 @@ class ScanMetrics:
             d["decode_s"] += row.decode_s
             d["stage_s"] += row.stage_s
             d["h2d_bytes"] += row.h2d_bytes
+
+    def fold_span(self, name: str, seconds: float) -> None:
+        """Add one ``span``'s duration (any thread)."""
+        with self._span_lock:
+            tot = self._spans.setdefault(name, [0.0, 0])
+            tot[0] += seconds
+            tot[1] += 1
 
     def finish(self) -> None:
         """Freeze the stream's wall clock — once.  The session calls this
@@ -215,6 +300,15 @@ class ScanMetrics:
             return None
         return self._extract_s / busy
 
+    def span_totals(self) -> dict[str, tuple[float, int]]:
+        """Seconds and count of each span name folded so far."""
+        with self._span_lock:
+            return {k: (v[0], v[1]) for k, v in self._spans.items()}
+
+    def counters(self) -> dict[str, int]:
+        """``COUNTERS`` totalled over live cells."""
+        return dict(self._counters)
+
     @property
     def step_s_total(self) -> float:
         return self._step_s
@@ -246,7 +340,6 @@ class ScanMetrics:
             label: {
                 "cells": d["cells"],
                 "busy_s": round(d["busy_s"], 4),
-                "utilization": round(d["busy_s"] / wall, 3) if wall > 0 else None,
                 "decode_s": round(d.get("decode_s", 0.0), 4),
                 "stage_s": round(d.get("stage_s", 0.0), 4),
                 "h2d_bytes": d.get("h2d_bytes", 0),
@@ -276,6 +369,8 @@ class ScanMetrics:
                 round(self._h2d_bytes / markers, 1) if markers > 0 else None
             ),
             "extract_share": round(share, 3) if share is not None else None,
+            **self.counters(),
+            "spans": {k: round(s, 4) for k, (s, _) in sorted(self.span_totals().items())},
             "per_device": per_device,
         }
 

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.api.metrics import CellTiming, ScanMetrics
+from repro.api.metrics import COUNTERS, CellTiming, ScanMetrics, span
 from repro.api.specs import ScanConfig
 from repro.api.study import Study
 from repro.core.engines import EngineContext, ScanEngine, get_engine
@@ -473,9 +473,11 @@ class _Slot:
     """
 
     def __init__(self, prepared: "PreparedScan", *, device=None,
-                 step: Callable[..., dict] | None = None, label: str = "serial"):
+                 step: Callable[..., dict] | None = None, label: str = "serial",
+                 metrics: ScanMetrics | None = None):
         self.device = device
         self.label = label
+        self.metrics = metrics
         self.state = prepared.engine.make_device_state(
             prepared.ctx, device=device, step=step
         )
@@ -493,10 +495,16 @@ class _Slot:
     def panel_block(self, batch: MarkerBatch, block: TraitBlock):
         """The trailing step argument for one grid cell: the slot's view of
         the driver's residualized store for OLS engines, the engine device
-        state's per-scope rotated panel for the rest."""
-        if self.panels is not None:
-            return self.panels.device_block(block)
-        return self.state.panel_block(batch, block)
+        state's per-scope rotated panel for the rest.  A ``gwas.panel`` span
+        on whichever thread asks (compute or look-ahead)."""
+        with self.span("panel"):
+            if self.panels is not None:
+                return self.panels.device_block(block)
+            return self.state.panel_block(batch, block)
+
+    def span(self, name: str, **args):
+        """``api.metrics.span`` under this slot's label and metrics."""
+        return span(name, self.label, self.metrics, **args)
 
     def reset(self) -> None:
         self.state.reset()
@@ -509,9 +517,11 @@ class _Slot:
 
 
 def _live_cell(
-    host_batch, out: dict, blk: TraitBlock, cfg: ScanConfig, dof: float
-) -> "CellResult":
-    """Wrap one device step output as a materialized live ``CellResult``.
+    host_batch, out: dict, blk: TraitBlock, cfg: ScanConfig, dof: float,
+    slot: _Slot,
+) -> tuple["CellResult", float]:
+    """Wrap one device step output as a materialized live ``CellResult``,
+    with the seconds its extraction took.
 
     ``arrays`` is forced here — on the computing slot's thread — so D2H
     pulls parallelize across devices, the per-cell wall time is honest
@@ -521,7 +531,9 @@ def _live_cell(
     the full tiles when the cell has hits.  ``dof`` plus the scan's screen
     threshold (``t2_screen``) let the view route every emitted -log10 p
     through the canonical refine executables (§13) — in both sparse and
-    dense epilogue modes, so the two stay bitwise equal.
+    dense epilogue modes, so the two stay bitwise equal.  The extraction
+    is one ``gwas.extract`` span of the computing ``slot``, holding its
+    pulls and refines; the refine tally knows the slot's device.
     """
     batch = host_batch.batch
     t2_screen = (
@@ -532,6 +544,7 @@ def _live_cell(
     view = BatchView(
         host_batch, out, blk.n_traits, t_lo=blk.lo, block_index=blk.index, dof=dof,
         t2_screen=t2_screen,
+        span=slot.span, refine_home=slot.device,
     )
     cell = CellResult(
         batch_index=batch.index,
@@ -543,8 +556,37 @@ def _live_cell(
         view=view,
         hit_threshold=cfg.hit_threshold_nlp,
     )
-    cell.arrays
-    return cell
+    with slot.span("extract") as took:
+        cell.arrays
+    return cell, took.seconds
+
+
+def _cell_timing(cell: "CellResult", *, step_s: float, extract_s: float,
+                 decode_s: float, stage_s: float, h2d_bytes: int,
+                 device: str) -> CellTiming:
+    """The accounting row of one live cell, its extraction counters read
+    off the cell's view."""
+    view = cell.view
+    sparse = view.is_sparse
+    return CellTiming(
+        batch_index=cell.batch_index,
+        block_index=cell.block_index,
+        n_markers=cell.n_markers,
+        n_traits=cell.n_traits,
+        wall_s=step_s + extract_s,
+        step_s=step_s,
+        extract_s=extract_s,
+        decode_s=decode_s,
+        stage_s=stage_s,
+        h2d_bytes=h2d_bytes,
+        device=device,
+        refine_launches=view.tally.launches,
+        refine_lanes=view.tally.lanes,
+        hits=int(cell.hits.shape[0]),
+        screen_count=view.screen_count if sparse else 0,
+        overflowed=sparse and view.overflowed,
+        foreign_refines=view.tally.foreign,
+    )
 
 
 class _SlotTail:
@@ -563,10 +605,12 @@ class _SlotTail:
     expire exactly as a worker crash would.
     """
 
-    def __init__(self, *, stop: threading.Event, on_error: Callable, name: str):
+    def __init__(self, *, stop: threading.Event, on_error: Callable, name: str,
+                 slot: _Slot):
         self._q: queue.Queue = queue.Queue(maxsize=4)
         self._stop = stop
         self._on_error = on_error
+        self._slot = slot
         self._failed = False
         self._thread = threading.Thread(target=self._run, daemon=True, name=name)
         self._thread.start()
@@ -590,7 +634,8 @@ class _SlotTail:
             if self._failed:
                 continue
             try:
-                task()
+                with self._slot.span("tail"):
+                    task()
             except BaseException as e:  # noqa: BLE001 — reported to consumer
                 self._failed = True
                 self._on_error(e)
@@ -611,9 +656,11 @@ class SerialExecutor:
 
     kind = "serial"
 
-    def __init__(self, prepared: "PreparedScan", *, step: Callable[..., dict] | None = None):
+    def __init__(self, prepared: "PreparedScan", *, step: Callable[..., dict] | None = None,
+                 metrics: ScanMetrics | None = None):
         self.prepared = prepared
         self._step = step
+        self.metrics = metrics
 
     def info(self) -> dict:
         return {"kind": self.kind, "devices": 1}
@@ -623,12 +670,13 @@ class SerialExecutor:
         cfg = prep.config
         engine = prep.engine
         blocks = prep.trait_blocks
-        slot = _Slot(prep, device=None, step=self._step, label="serial")
+        slot = _Slot(prep, device=None, step=self._step, label="serial",
+                     metrics=self.metrics)
 
         def decode(b):
-            t = time.perf_counter()
-            hb = engine.prepare_batch(prep.study.source, b, prep.ctx)
-            return hb, time.perf_counter() - t
+            with slot.span("decode") as took:
+                hb = engine.prepare_batch(prep.study.source, b, prep.ctx)
+            return hb, took.seconds
 
         prefetched = Prefetcher(
             todo,
@@ -644,15 +692,22 @@ class SerialExecutor:
             # Staging launches the copy; on accelerators it completes while
             # the device chews on the previous batch (double buffer).
             host_batch, decode_s = item
-            t = time.perf_counter()
-            dev_args = slot.stage(host_batch)
+            with slot.span("stage") as took:
+                dev_args = slot.stage(host_batch)
             h2d = sum(int(getattr(a, "nbytes", 0)) for a in host_batch.device_args)
-            return host_batch, dev_args, decode_s, time.perf_counter() - t, h2d
+            return host_batch, dev_args, decode_s, took.seconds, h2d
 
         stream = double_buffer(prefetched, stage)
         try:
             todo_pos = {b.index: i for i, b in enumerate(todo)}
-            for host_batch, dev_args, decode_s, stage_s, h2d_bytes in stream:
+            while True:
+                # The compute thread's wait for its next decoded batch; the
+                # double buffer stages it (a ``stage`` span inside).
+                with slot.span("wait_input"):
+                    got = next(stream, None)
+                if got is None:
+                    break
+                host_batch, dev_args, decode_s, stage_s, h2d_bytes = got
                 batch = host_batch.batch
                 bidx = batch.index
                 # Trait blocks are the INNER loop: one staged genotype batch
@@ -664,8 +719,9 @@ class SerialExecutor:
                 nxt = todo_pos.get(bidx, len(todo)) + 1
                 next_batch = todo[nxt] if nxt < len(todo) else None
                 for pos, blk in enumerate(cells):
-                    t0 = time.perf_counter()
-                    out = slot.step(*dev_args, slot.panel_block(batch, blk))
+                    panel = slot.panel_block(batch, blk)
+                    with slot.span("dispatch") as dispatch:
+                        out = slot.step(*dev_args, panel)
                     # Look ahead one cell on the trait axis (then wrap to the
                     # next batch's first block, which the LRU may have evicted).
                     # Requested BEFORE the device sync so staging overlaps
@@ -674,27 +730,24 @@ class SerialExecutor:
                         panel_la.request(batch, cells[pos + 1])
                     elif next_batch is not None and blocks:
                         panel_la.request(next_batch, blocks[0])
-                    # Split the cell's wall time at the device fence: the
-                    # jitted step dispatches asynchronously, so t1 - t0 is
-                    # honest device time and t2 - t1 is the host payload
-                    # extraction the sparse epilogue (§13) shrinks.
-                    jax.block_until_ready(out)
-                    t1 = time.perf_counter()
-                    cell = _live_cell(host_batch, out, blk, cfg, prep.dof)
-                    t2 = time.perf_counter()
-                    yield cell, CellTiming(
-                        batch_index=bidx,
-                        block_index=blk.index,
-                        n_markers=cell.n_markers,
-                        n_traits=cell.n_traits,
-                        wall_s=t2 - t0,
-                        step_s=t1 - t0,
-                        extract_s=t2 - t1,
-                        # Attributed to the batch's first cell; later cells
-                        # of the sweep reuse the staged copy.
-                        decode_s=decode_s if pos == 0 else 0.0,
-                        stage_s=stage_s if pos == 0 else 0.0,
-                        h2d_bytes=h2d_bytes if pos == 0 else 0,
+                    # Split the cell's time at the device fence: the jitted
+                    # step dispatches asynchronously, so dispatch + fence is
+                    # honest device time and the extraction after it is the
+                    # host payload work the sparse epilogue (§13) shrinks.
+                    with slot.span("fence") as fence:
+                        jax.block_until_ready(out)
+                    step_s = dispatch.seconds + fence.seconds
+                    cell, extract_s = _live_cell(
+                        host_batch, out, blk, cfg, prep.dof, slot
+                    )
+                    # Attributed to the batch's first cell; later cells of
+                    # the sweep reuse the staged copy.
+                    first = pos == 0
+                    yield cell, _cell_timing(
+                        cell, step_s=step_s, extract_s=extract_s,
+                        decode_s=decode_s if first else 0.0,
+                        stage_s=stage_s if first else 0.0,
+                        h2d_bytes=h2d_bytes if first else 0,
                         device=slot.label,
                     )
         finally:
@@ -756,7 +809,8 @@ class MultiDeviceExecutor:
     def __init__(self, prepared: "PreparedScan", *, n_devices: int,
                  placement: str = "marker-major", lease_batches: int = 2,
                  backend: str = "threads", backend_opts: dict | None = None,
-                 slot_prefetch: int = 1, autotune_lease: bool = True):
+                 slot_prefetch: int = 1, autotune_lease: bool = True,
+                 metrics: ScanMetrics | None = None):
         visible = jax.devices()
         if n_devices > len(visible):
             raise ValueError(
@@ -772,6 +826,7 @@ class MultiDeviceExecutor:
         self.backend_opts = dict(backend_opts or {})
         self.slot_prefetch = max(0, int(slot_prefetch))
         self.autotune_lease = bool(autotune_lease)
+        self.metrics = metrics
         # Under a distributed backend the worker labels are host-qualified
         # (CellTiming.device, summary.json worker stats): N processes share
         # one grid, and "dev0" alone no longer names a unique slot.
@@ -845,10 +900,11 @@ class MultiDeviceExecutor:
                     if stop.is_set():
                         return
 
-        def decode(batch):
-            t = time.perf_counter()
-            hb = engine.prepare_batch(prep.study.source, batch, prep.ctx)
-            return hb, time.perf_counter() - t
+        def decode(job):
+            batch, slot = job
+            with slot.span("decode") as took:
+                hb = engine.prepare_batch(prep.study.source, batch, prep.ctx)
+            return hb, took.seconds
 
         # ONE pool across every slot: total host decode parallelism is
         # io_workers — the same meaning the knob has under the serial
@@ -857,13 +913,14 @@ class MultiDeviceExecutor:
 
         def worker(wid: int, device) -> None:
             label = f"{self._label_prefix}dev{wid}"
-            slot = _Slot(prep, device=device, label=label)
+            slot = _Slot(prep, device=device, label=label, metrics=self.metrics)
             panel_la = (
                 PanelPrefetcher(slot.panel_block, name=f"panel-prefetch-dev{wid}")
                 if depth > 0 else None
             )
             tail = (
-                _SlotTail(stop=stop, on_error=put, name=f"slot-tail-{wid}")
+                _SlotTail(stop=stop, on_error=put, name=f"slot-tail-{wid}",
+                          slot=slot)
                 if depth > 0 else None
             )
             # Staged memo, capacity depth+1: the batch being computed plus
@@ -875,7 +932,7 @@ class MultiDeviceExecutor:
 
             def ensure_decode(batch) -> None:
                 if batch.index not in staged and batch.index not in inflight:
-                    pool.submit((wid, batch.index), batch)
+                    pool.submit((wid, batch.index), (batch, slot))
                     inflight.add(batch.index)
 
             def staged_args(batch) -> tuple:
@@ -884,15 +941,13 @@ class MultiDeviceExecutor:
                         hb, decode_s = pool.result((wid, batch.index))
                         inflight.discard(batch.index)
                     else:
-                        hb, decode_s = decode(batch)
-                    t = time.perf_counter()
-                    dev_args = slot.stage(hb)
+                        hb, decode_s = decode((batch, slot))
+                    with slot.span("stage") as took:
+                        dev_args = slot.stage(hb)
                     h2d = sum(
                         int(getattr(a, "nbytes", 0)) for a in hb.device_args
                     )
-                    staged[batch.index] = (
-                        hb, dev_args, decode_s, time.perf_counter() - t, h2d
-                    )
+                    staged[batch.index] = (hb, dev_args, decode_s, took.seconds, h2d)
                     while len(staged) > depth + 1:
                         oldest = next(iter(staged))
                         if oldest == batch.index:
@@ -900,28 +955,18 @@ class MultiDeviceExecutor:
                         del staged[oldest]
                 return staged[batch.index]
 
-            def make_emit(hb, out, blk, batch, step_s, decode_s, stage_s,
-                          h2d_bytes):
+            def make_emit(hb, out, blk, step_s, decode_s, stage_s, h2d_bytes):
                 def emit() -> None:
-                    t = time.perf_counter()
-                    cell = _live_cell(hb, out, blk, cfg, prep.dof)
+                    cell, extract_s = _live_cell(hb, out, blk, cfg, prep.dof, slot)
                     if self.commit is not None:
-                        self.commit(cell)
-                    extract_s = time.perf_counter() - t
-                    put((cell, CellTiming(
-                        batch_index=batch.index,
-                        block_index=blk.index,
-                        n_markers=cell.n_markers,
-                        n_traits=cell.n_traits,
-                        # Not contiguous wall clock under the pipeline: the
-                        # extract ran later, overlapped with another cell's
-                        # step.  step + extract is the cell's true cost.
-                        wall_s=step_s + extract_s,
-                        step_s=step_s,
-                        extract_s=extract_s,
-                        decode_s=decode_s,
-                        stage_s=stage_s,
-                        h2d_bytes=h2d_bytes,
+                        with slot.span("sinks"):
+                            self.commit(cell)
+                    # wall_s (step + extract) is not contiguous wall clock
+                    # under the pipeline: the extract ran later, overlapped
+                    # with another cell's step.
+                    put((cell, _cell_timing(
+                        cell, step_s=step_s, extract_s=extract_s,
+                        decode_s=decode_s, stage_s=stage_s, h2d_bytes=h2d_bytes,
                         device=label,
                     )))
                 return emit
@@ -935,7 +980,8 @@ class MultiDeviceExecutor:
                     # peers' undone leases): a worker with work in hand
                     # must never park on the queue.
                     while len(ahead) < depth + 1:
-                        got = sched.claim(label, block=not ahead)
+                        with slot.span("claim"):
+                            got = sched.claim(label, block=not ahead)
                         if got is None:
                             break
                         if depth > 0:
@@ -945,15 +991,17 @@ class MultiDeviceExecutor:
                         break
                     idx, run = ahead.popleft()
                     batch = run.batch
-                    hb, dev_args, decode_s, stage_s, h2d_bytes = staged_args(batch)
+                    with slot.span("wait_input"):
+                        hb, dev_args, decode_s, stage_s, h2d_bytes = staged_args(batch)
                     # decode/stage are attributed to the first cell computed
                     # off a fresh staging, once.
                     staged[batch.index] = (hb, dev_args, 0.0, 0.0, 0)
                     for pos, blk in enumerate(run.blocks):
                         if stop.is_set():
                             return
-                        t0 = time.perf_counter()
-                        out = slot.step(*dev_args, slot.panel_block(batch, blk))
+                        panel = slot.panel_block(batch, blk)
+                        with slot.span("dispatch") as dispatch:
+                            out = slot.step(*dev_args, panel)
                         # Overlap windows open between dispatch and fence:
                         # the next cell's panel block and (first cell of the
                         # run only) the look-ahead H2D staging.
@@ -973,16 +1021,16 @@ class MultiDeviceExecutor:
                                 (wid, nxt.index)
                             ):
                                 staged_args(nxt)
-                        jax.block_until_ready(out)
-                        step_s = time.perf_counter() - t0
+                        with slot.span("fence") as fence:
+                            jax.block_until_ready(out)
+                        step_s = dispatch.seconds + fence.seconds
                         if label not in self._slot_devices:
                             self._slot_devices[label] = sorted(
                                 {str(d) for a in jax.tree.leaves(out)
                                  for d in a.devices()}
                             )
                         emit = make_emit(
-                            hb, out, blk, batch, step_s, decode_s, stage_s,
-                            h2d_bytes,
+                            hb, out, blk, step_s, decode_s, stage_s, h2d_bytes
                         )
                         if tail is not None:
                             tail.submit(emit)
@@ -1330,8 +1378,9 @@ class ScanSession:
                 backend_opts=self._backend_opts(),
                 slot_prefetch=self.config.slot_prefetch,
                 autotune_lease=self.config.autotune_lease,
+                metrics=self.metrics,
             )
-        return SerialExecutor(self.prepared, step=self._step)
+        return SerialExecutor(self.prepared, step=self._step, metrics=self.metrics)
 
     def events(self) -> Iterator[CellResult]:
         """Stream the grid: compute pending cells on the configured executor
@@ -1374,18 +1423,25 @@ class ScanSession:
         stream = executor.cells(todo, pending)
         try:
             for cell, timing in stream:
-                if ckpt is not None and not distributed:
-                    # Commit the shard, then the manifest — a crash between
-                    # the two just re-does one grid cell.  Commit-before-
-                    # yield makes the manifest the multi-device coordination
-                    # substrate: double completion (work stealing) is an
-                    # idempotent overwrite, and a resume under any device
-                    # count skips exactly the committed cells.
-                    ckpt.commit_cell(cell.batch_index, cell.block_index, cell.payload())
-                computed.add((cell.batch_index, cell.block_index))
-                self.metrics.record(timing)
-                if self.progress is not None:
-                    self.progress(self.metrics)
+                # The cell's counters ride the span as arguments, so a
+                # profiler trace carries them beside the timings.
+                with span("sinks", timing.device, self.metrics,
+                          **{k: int(getattr(timing, k)) for k in COUNTERS}):
+                    if ckpt is not None and not distributed:
+                        # Commit the shard, then the manifest — a crash
+                        # between the two just re-does one grid cell.
+                        # Commit-before-yield makes the manifest the
+                        # multi-device coordination substrate: double
+                        # completion (work stealing) is an idempotent
+                        # overwrite, and a resume under any device count
+                        # skips exactly the committed cells.
+                        ckpt.commit_cell(
+                            cell.batch_index, cell.block_index, cell.payload()
+                        )
+                    computed.add((cell.batch_index, cell.block_index))
+                    self.metrics.record(timing)
+                    if self.progress is not None:
+                        self.progress(self.metrics)
                 yield cell
         finally:
             # Error path included: a raising consumer or engine step must

@@ -36,6 +36,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro.api.metrics import span
 from repro.core.sinks import BestTraitSink, LambdaGCSink, QCSink
 
 __all__ = [
@@ -382,10 +383,11 @@ class _AccumulatingWriter(ResultWriter):
         self._start()
 
     def write(self, cell: Any) -> None:
-        self._best.on_cell(cell)
-        self._qc.on_cell(cell)
-        self._lam.on_cell(cell)
-        self._hits.add(cell)
+        with span("write", metrics=getattr(self._session, "metrics", None)):
+            self._best.on_cell(cell)
+            self._qc.on_cell(cell)
+            self._lam.on_cell(cell)
+            self._hits.add(cell)
 
     def close(self) -> dict:
         self._hits.finish()

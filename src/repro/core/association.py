@@ -187,19 +187,23 @@ def assoc_from_standardized(
     """Association statistics from pre-standardized inputs (both zero-mean,
     unit population variance).  This is the function the distributed scan
     jits; shapes ``(M, N) x (N, P) -> (M, P)``.  ``trait_tile`` — see
-    ``correlation``."""
-    r = correlation(
-        g_std, y_std, n_samples, precision=options.precision, trait_tile=trait_tile
-    )
-    # Guard: standardization guarantees |r| <= 1 up to rounding; clamp so the
-    # epilogue stays finite even for degenerate columns.
-    r = jnp.clip(r, -1.0, 1.0)
+    ``correlation``.  The GEMM and t run under the ``gwas.assoc`` scope,
+    the dense -log10 p tile under ``gwas.epilogue``."""
     dof = options.dof(n_samples, n_covariates)
-    t = _stats.t_from_r(r, dof, eps=options.eps)
-    if options.compute_neglog10p and not options.sparse_epilogue:
-        nlp = _stats.neglog10_p_from_t(t, dof)
-    else:
-        nlp = jnp.zeros_like(t)
+    with jax.named_scope("gwas.assoc"):
+        r = correlation(
+            g_std, y_std, n_samples, precision=options.precision,
+            trait_tile=trait_tile,
+        )
+        # Guard: standardization guarantees |r| <= 1 up to rounding; clamp
+        # so the epilogue stays finite even for degenerate columns.
+        r = jnp.clip(r, -1.0, 1.0)
+        t = _stats.t_from_r(r, dof, eps=options.eps)
+    with jax.named_scope("gwas.epilogue"):
+        if options.compute_neglog10p and not options.sparse_epilogue:
+            nlp = _stats.neglog10_p_from_t(t, dof)
+        else:
+            nlp = jnp.zeros_like(t)
     return AssocResult(r=r, t=t, neglog10p=nlp)
 
 
@@ -327,36 +331,44 @@ def sparse_epilogue_outputs(
     ``screen`` optionally supplies ``(hit_idx, screen_count)`` from a fused
     kernel (``kernels.tstat.screen_compact``) instead of the XLA
     nonzero-gather; the compaction layout is identical either way.
+
+    Runs under the ``gwas.epilogue`` scope: the winners under
+    ``gwas.epilogue.best``, the screen and compaction under
+    ``gwas.epilogue.compact``.
     """
     del dof  # the refine is host-side now; kept for call-site symmetry
-    t2 = jnp.square(t)
-    # argmax over the transposed tile: per-trait reductions then run along
-    # contiguous memory (~1.7x faster on XLA CPU) and the result is the same
-    # int32 — argmax keeps first-occurrence ties along the marker axis in
-    # either layout.
-    best_row = jnp.argmax(t2.T, axis=1).astype(jnp.int32)
-    best_t = jnp.take_along_axis(t, best_row[None, :], axis=0)[0]
-    if screen is None:
-        keep = t2.ravel() >= plan.t2_screen
-        screen_count = jnp.sum(keep).astype(jnp.int32)
-        # nonzero lowers to a full-length serial cumsum on XLA CPU — by far
-        # the most expensive op in the epilogue.  Almost every tile of a
-        # genome scan has zero survivors, so gate the compaction on the cheap
-        # reduction: the empty branch emits exactly what nonzero(fill_value=-1)
-        # would (all -1), so emitted bits are unchanged in every case.
-        idx = jax.lax.cond(
-            screen_count > 0,
-            lambda: jnp.nonzero(keep, size=plan.capacity, fill_value=-1)[0].astype(
-                jnp.int32
-            ),
-            lambda: jnp.full((plan.capacity,), -1, jnp.int32),
-        )
-    else:
-        idx, screen_count = screen
-    slot = idx >= 0
-    safe = jnp.maximum(idx, 0)
-    hit_t = jnp.where(slot, t.ravel()[safe], 0.0)
-    hit_r = jnp.where(slot, r.ravel()[safe], 0.0)
+    with jax.named_scope("gwas.epilogue"):
+        t2 = jnp.square(t)
+        with jax.named_scope("gwas.epilogue.best"):
+            # argmax over the transposed tile: per-trait reductions then run
+            # along contiguous memory (~1.7x faster on XLA CPU) and the
+            # result is the same int32 — argmax keeps first-occurrence ties
+            # along the marker axis in either layout.
+            best_row = jnp.argmax(t2.T, axis=1).astype(jnp.int32)
+            best_t = jnp.take_along_axis(t, best_row[None, :], axis=0)[0]
+        with jax.named_scope("gwas.epilogue.compact"):
+            if screen is None:
+                keep = t2.ravel() >= plan.t2_screen
+                screen_count = jnp.sum(keep).astype(jnp.int32)
+                # nonzero lowers to a full-length serial cumsum on XLA CPU —
+                # by far the most expensive op in the epilogue.  Almost every
+                # tile of a genome scan has zero survivors, so gate the
+                # compaction on the cheap reduction: the empty branch emits
+                # exactly what nonzero(fill_value=-1) would (all -1), so
+                # emitted bits are unchanged in every case.
+                idx = jax.lax.cond(
+                    screen_count > 0,
+                    lambda: jnp.nonzero(
+                        keep, size=plan.capacity, fill_value=-1
+                    )[0].astype(jnp.int32),
+                    lambda: jnp.full((plan.capacity,), -1, jnp.int32),
+                )
+            else:
+                idx, screen_count = screen
+            slot = idx >= 0
+            safe = jnp.maximum(idx, 0)
+            hit_t = jnp.where(slot, t.ravel()[safe], 0.0)
+            hit_r = jnp.where(slot, r.ravel()[safe], 0.0)
     return {
         "batch_best_row": best_row,
         "batch_best_t": best_t,

@@ -528,53 +528,57 @@ def build_dense_step(
         else options
     )
 
-    def prolog(g_raw: jax.Array):
-        g_std, ms = standardize_genotype_batch(g_raw)
-        if options.dof_mode == "exact":
-            from repro.core.residualize import residualize_genotypes
+    def gwas_dense_prolog(g_raw: jax.Array):
+        with jax.named_scope("gwas.device_decode"):
+            g_std, ms = standardize_genotype_batch(g_raw)
+            if options.dof_mode == "exact":
+                from repro.core.residualize import residualize_genotypes
 
-            g_std = residualize_genotypes(g_std, q_basis)
-        valid = ms.valid & (ms.maf >= maf_min) if maf_min > 0 else ms.valid
+                g_std = residualize_genotypes(g_std, q_basis)
+            valid = ms.valid & (ms.maf >= maf_min) if maf_min > 0 else ms.valid
         return g_std, ms.maf, valid
 
-    def cell(g_std, maf, valid, y_std) -> dict[str, jax.Array]:
+    def gwas_dense_cell(g_std, maf, valid, y_std) -> dict[str, jax.Array]:
         res = assoc_from_standardized(
             g_std, y_std, n_samples=n_samples, n_covariates=n_covariates,
             options=cell_options, trait_tile=trait_tile,
         )
-        mask = valid[:, None]
-        r = jnp.where(mask, res.r, 0.0)
-        t = jnp.where(mask, res.t, 0.0)
+        with jax.named_scope("gwas.epilogue"):
+            mask = valid[:, None]
+            r = jnp.where(mask, res.r, 0.0)
+            t = jnp.where(mask, res.t, 0.0)
         out = {"r": r, "t": t, "maf": maf, "valid": valid}
         if sparse is not None:
             out.update(sparse_epilogue_outputs(r, t, dof, sparse))
         else:
-            nlp = jnp.where(mask, res.neglog10p, 0.0)
-            out["nlp"] = nlp
-            out.update(_dense_best_and_hits(nlp, t, hit_threshold))
+            with jax.named_scope("gwas.epilogue"):
+                nlp = jnp.where(mask, res.neglog10p, 0.0)
+                out["nlp"] = nlp
+                out.update(_dense_best_and_hits(nlp, t, hit_threshold))
         if multivariate:
             from repro.core import multivariate as mv
 
-            omni, omni_nlp = mv.omnibus_chi2(
-                out["r"], n_samples, n_traits_eff, whitening=whitening
-            )
+            with jax.named_scope("gwas.epilogue"):
+                omni, omni_nlp = mv.omnibus_chi2(
+                    out["r"], n_samples, n_traits_eff, whitening=whitening
+                )
             out["omnibus"] = omni
             out["omnibus_nlp"] = omni_nlp
         return out
 
-    def step_monolithic(g_raw: jax.Array, y_std: jax.Array) -> dict[str, jax.Array]:
-        return cell(*prolog(g_raw), y_std)
+    def gwas_dense_step(g_raw: jax.Array, y_std: jax.Array) -> dict[str, jax.Array]:
+        return gwas_dense_cell(*gwas_dense_prolog(g_raw), y_std)
 
     if mesh is None:
         if not split_prolog:
-            mono_j = jax.jit(step_monolithic)
+            mono_j = jax.jit(gwas_dense_step)
             if not packed_input:
                 return mono_j
             # Decode-then-mono as two executables: the mono program is the
             # exact compiled artifact dense staging runs.
             return lambda g_raw, y_std: mono_j(decode(g_raw), y_std)
-        prolog_j = jax.jit(prolog)
-        cell_j = jax.jit(cell)
+        prolog_j = jax.jit(gwas_dense_prolog)
+        cell_j = jax.jit(gwas_dense_cell)
     else:
         sh = gwas_shardings(mesh, mode=mode)
         mv_spec = {"omnibus": sh["marker_vec"], "omnibus_nlp": sh["marker_vec"]} if multivariate else {}
@@ -594,15 +598,15 @@ def build_dense_step(
         }
         if not split_prolog:
             return jax.jit(
-                step_monolithic, in_shardings=(sh["g"], sh["y"]), out_shardings=out_shardings
+                gwas_dense_step, in_shardings=(sh["g"], sh["y"]), out_shardings=out_shardings
             )
         prolog_j = jax.jit(
-            prolog,
+            gwas_dense_prolog,
             in_shardings=(sh["g"],),
             out_shardings=(sh["g"], sh["marker_vec"], sh["marker_vec"]),
         )
         cell_j = jax.jit(
-            cell,
+            gwas_dense_cell,
             in_shardings=(sh["g"], sh["marker_vec"], sh["marker_vec"], sh["y"]),
             out_shardings=out_shardings,
         )
@@ -702,30 +706,33 @@ def build_fused_step(
     else:
         kernel_fn = kernel_local
 
-    def step(packed, mean2d, inv2d, valid, y_std):
+    def gwas_fused_step(packed, mean2d, inv2d, valid, y_std):
         p_true = y_std.shape[1]
         pad_p = (-p_true) % block_p
         pad_n = packed.shape[1] * 4 - y_std.shape[0]  # packed samples are tile-padded
-        if pad_p or pad_n:
-            y_std = jnp.pad(y_std, ((0, pad_n), (0, pad_p)))
-        r, t = kernel_fn(packed, mean2d, inv2d, y_std)
-        if pad_p:
-            r = r[:, :p_true]
-            t = t[:, :p_true]
-        mask = valid[:, None]
-        r = jnp.where(mask, r, 0.0)
-        t = jnp.where(mask, t, 0.0)
+        with jax.named_scope("gwas.assoc"):
+            if pad_p or pad_n:
+                y_std = jnp.pad(y_std, ((0, pad_n), (0, pad_p)))
+            r, t = kernel_fn(packed, mean2d, inv2d, y_std)
+            if pad_p:
+                r = r[:, :p_true]
+                t = t[:, :p_true]
+        with jax.named_scope("gwas.epilogue"):
+            mask = valid[:, None]
+            r = jnp.where(mask, r, 0.0)
+            t = jnp.where(mask, t, 0.0)
         out = {"r": r, "t": t}
         if sparse is not None:
             out.update(sparse_epilogue_outputs(r, t, dof, sparse))
         else:
-            nlp = jnp.where(mask, _stats.neglog10_p_from_t(t, dof), 0.0)
-            out["nlp"] = nlp
-            out.update(_dense_best_and_hits(nlp, t, hit_threshold))
+            with jax.named_scope("gwas.epilogue"):
+                nlp = jnp.where(mask, _stats.neglog10_p_from_t(t, dof), 0.0)
+                out["nlp"] = nlp
+                out.update(_dense_best_and_hits(nlp, t, hit_threshold))
         return out
 
     if mesh is None:
-        step_j = jax.jit(step)
+        step_j = jax.jit(gwas_fused_step)
         if not packed_input:
             return step_j
         from repro.kernels.gwas_dot import ops as kops
@@ -751,7 +758,7 @@ def build_fused_step(
     sh = gwas_shardings(mesh, mode="mp")
     model_vec = NamedSharding(mesh, P("model"))
     return jax.jit(
-        step,
+        gwas_fused_step,
         in_shardings=(sh["packed"], sh["packed"], sh["packed"], sh["marker_vec"], sh["y"]),
         out_shardings={
             "r": sh["out"],
@@ -828,15 +835,17 @@ def build_lmm_step(
     from repro.core.association import correlation
     from repro.core.residualize import residualize_genotypes
 
-    def prolog(g_raw, rotation, qhat):
-        g_std, ms = standardize_genotype_batch(g_raw)
-        g_rot = jax.lax.dot_general(
-            g_std, rotation, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-        g_fin = residualize_genotypes(g_rot, qhat)
-        valid = ms.valid & (ms.maf >= maf_min) if maf_min > 0 else ms.valid
+    def gwas_lmm_prolog(g_raw, rotation, qhat):
+        with jax.named_scope("gwas.device_decode"):
+            g_std, ms = standardize_genotype_batch(g_raw)
+            valid = ms.valid & (ms.maf >= maf_min) if maf_min > 0 else ms.valid
+        with jax.named_scope("gwas.assoc"):
+            g_rot = jax.lax.dot_general(
+                g_std, rotation, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
+            g_fin = residualize_genotypes(g_rot, qhat)
         return g_fin, ms.maf, valid
 
     cell_opts = (
@@ -844,64 +853,68 @@ def build_lmm_step(
         else opts
     )
 
-    def cell(g_fin, maf, valid, y_std):
+    def gwas_lmm_cell(g_fin, maf, valid, y_std):
         mask = valid[:, None]
         screen = None
         nlp = None
         if epilogue == "fused":
-            r = jnp.clip(
-                correlation(g_fin, y_std, n_samples, precision=opts.precision,
-                            trait_tile=block_p),
-                -1.0, 1.0,
-            )
-            # Mask before the kernel: invalid lanes map to r=0 -> t=0
-            # exactly, so masked tiles are identical either way and the
-            # fused screen can never admit a masked lane.
-            r = jnp.where(mask, r, 0.0)
-            if sparse is not None:
-                from repro.kernels.tstat import screen_compact
-
-                t, idx, screen_count = screen_compact(
-                    r, dof, sparse.t2_screen, sparse.capacity,
-                    block_m=block_m, block_p=block_p,
+            with jax.named_scope("gwas.assoc"):
+                r = jnp.clip(
+                    correlation(g_fin, y_std, n_samples, precision=opts.precision,
+                                trait_tile=block_p),
+                    -1.0, 1.0,
                 )
-                screen = (idx, screen_count)
-            else:
-                from repro.kernels.tstat import tstat
+            with jax.named_scope("gwas.epilogue"):
+                # Mask before the kernel: invalid lanes map to r=0 -> t=0
+                # exactly, so masked tiles are identical either way and the
+                # fused screen can never admit a masked lane.
+                r = jnp.where(mask, r, 0.0)
+                if sparse is not None:
+                    from repro.kernels.tstat import screen_compact
 
-                t = tstat(r, dof, block_m=block_m, block_p=block_p)
-                nlp = jnp.where(mask, _stats.neglog10_p_from_t(t, dof), 0.0)
+                    t, idx, screen_count = screen_compact(
+                        r, dof, sparse.t2_screen, sparse.capacity,
+                        block_m=block_m, block_p=block_p,
+                    )
+                    screen = (idx, screen_count)
+                else:
+                    from repro.kernels.tstat import tstat
+
+                    t = tstat(r, dof, block_m=block_m, block_p=block_p)
+                    nlp = jnp.where(mask, _stats.neglog10_p_from_t(t, dof), 0.0)
         else:
             res = assoc_from_standardized(
                 g_fin, y_std, n_samples=n_samples, n_covariates=n_covariates,
                 options=cell_opts, trait_tile=block_p,
             )
-            r = jnp.where(mask, res.r, 0.0)
-            t = jnp.where(mask, res.t, 0.0)
-            if sparse is None:
-                nlp = jnp.where(mask, res.neglog10p, 0.0)
+            with jax.named_scope("gwas.epilogue"):
+                r = jnp.where(mask, res.r, 0.0)
+                t = jnp.where(mask, res.t, 0.0)
+                if sparse is None:
+                    nlp = jnp.where(mask, res.neglog10p, 0.0)
         out = {"r": r, "t": t, "maf": maf, "valid": valid}
         if sparse is not None:
             out.update(sparse_epilogue_outputs(r, t, dof, sparse, screen=screen))
         else:
-            out["nlp"] = nlp
-            out.update(_dense_best_and_hits(nlp, t, hit_threshold))
+            with jax.named_scope("gwas.epilogue"):
+                out["nlp"] = nlp
+                out.update(_dense_best_and_hits(nlp, t, hit_threshold))
         return out
 
     if mesh is None:
-        prolog_j = jax.jit(prolog)
-        cell_j = jax.jit(cell)
+        prolog_j = jax.jit(gwas_lmm_prolog)
+        cell_j = jax.jit(gwas_lmm_cell)
     else:
         sh = gwas_shardings(mesh, mode="mp")
         rep = NamedSharding(mesh, P())
         model_vec = NamedSharding(mesh, P("model"))
         prolog_j = jax.jit(
-            prolog,
+            gwas_lmm_prolog,
             in_shardings=(sh["g"], rep, rep),
             out_shardings=(sh["g"], sh["marker_vec"], sh["marker_vec"]),
         )
         cell_j = jax.jit(
-            cell,
+            gwas_lmm_cell,
             in_shardings=(sh["g"], sh["marker_vec"], sh["marker_vec"], sh["y"]),
             out_shardings={
                 "r": sh["out"],

@@ -27,8 +27,9 @@ it.
 """
 from __future__ import annotations
 
+import contextlib
 import os
-from typing import Any
+from typing import Any, Callable
 
 import jax.numpy as jnp
 import numpy as np
@@ -50,7 +51,10 @@ def _screen_any(t_tile, t2_screen: float) -> bool:
     if _T2MAX_PROBE is None:
         import jax
 
-        _T2MAX_PROBE = jax.jit(lambda t: jnp.max(jnp.square(t)))
+        def gwas_t2max_probe(t):
+            return jnp.max(jnp.square(t))
+
+        _T2MAX_PROBE = jax.jit(gwas_t2max_probe)
     return bool(np.asarray(_T2MAX_PROBE(t_tile)) >= np.float32(t2_screen))
 
 
@@ -115,18 +119,16 @@ def extract_hits(view: "BatchView", threshold: float) -> tuple[np.ndarray, np.nd
         # the same (capacity,)-shaped executable the compact path uses —
         # chunk 0 of the zero-padded buffer is elementwise identical to a
         # non-overflowed compact buffer, so every emitted bit matches.
-        if "t" not in view._cache and not _screen_any(
-            view._out["t"], view.t2_screen
-        ):
-            return hits, stats
+        if "t" not in view._cache:
+            with view.span("pull"):
+                if not _screen_any(view._out["t"], view.t2_screen):
+                    return hits, stats
         t_np = view.t
         flat_t = np.ascontiguousarray(t_np, np.float32).ravel()
         survivors = np.nonzero(np.square(flat_t) >= np.float32(view.t2_screen))[0]
         if survivors.size == 0:
             return hits, stats
-        nlp_vals = _stats.refine_neglog10p(
-            flat_t[survivors], view.dof, width=_stats.REFINE_WIDTH
-        ).astype(np.float32)
+        nlp_vals = view.refine(flat_t[survivors], width=_stats.REFINE_WIDTH)
         keep = nlp_vals >= threshold
         if keep.any():
             flat = survivors[keep].astype(np.int64)
@@ -188,6 +190,12 @@ class BatchView:
     the configured capacity.  ``t2_screen`` carries the scan's screen
     threshold so dense-mode extraction can mirror the sparse screen
     exactly.
+
+    ``span`` (name -> context manager; ``api.metrics.span`` bound to the
+    slot) times every D2H pull and refine call; ``tally`` counts the
+    refine's launches.  ``refine_home`` is the device the view's slot
+    computes on (None: the default device), against which the tally
+    counts refines that ran elsewhere.
     """
 
     def __init__(
@@ -200,6 +208,8 @@ class BatchView:
         block_index: int = 0,
         dof: float | None = None,
         t2_screen: float | None = None,
+        span: Callable[[str], Any] | None = None,
+        refine_home: Any = None,
     ):
         self.batch: MarkerBatch = host.batch
         self.host = host
@@ -212,11 +222,22 @@ class BatchView:
         self.t2_screen = t2_screen
         self.m_batch = host.batch.n_markers
         self._cache: dict[str, np.ndarray] = {}
+        self.span = span if span is not None else _no_span
+        self.tally = _stats.RefineTally(refine_home)
 
     def _pull(self, key: str) -> np.ndarray:
         if key not in self._cache:
-            self._cache[key] = np.asarray(self._out[key])
+            with self.span("pull"):
+                self._cache[key] = np.asarray(self._out[key])
         return self._cache[key]
+
+    def refine(self, t_values: np.ndarray, *, width: int | None = None) -> np.ndarray:
+        """``stats.refine_neglog10p`` at this cell's dof, as float32: one
+        ``refine`` span, every launch tallied."""
+        with self.span("refine"):
+            return _stats.refine_neglog10p(
+                t_values, float(self.dof), width=width, tally=self.tally
+            ).astype(np.float32)
 
     @property
     def is_sparse(self) -> bool:
@@ -261,9 +282,9 @@ class BatchView:
             if "hit_nlp" in self._out:  # synthetic/raw step dicts
                 self._cache["hit_nlp"] = np.asarray(self._out["hit_nlp"])
             else:
-                self._cache["hit_nlp"] = _stats.refine_neglog10p(
-                    self.hit_t, float(self.dof), width=_stats.REFINE_WIDTH
-                ).astype(np.float32)
+                self._cache["hit_nlp"] = self.refine(
+                    self.hit_t, width=_stats.REFINE_WIDTH
+                )
         return self._cache["hit_nlp"]
 
     @property
@@ -279,9 +300,9 @@ class BatchView:
         back to the in-step tile value."""
         if "batch_best_t" in self._out and self.dof is not None:
             if "best_nlp" not in self._cache:
-                self._cache["best_nlp"] = _stats.refine_neglog10p(
-                    self._pull("batch_best_t")[: self.n_traits], float(self.dof)
-                ).astype(np.float32)
+                self._cache["best_nlp"] = self.refine(
+                    self._pull("batch_best_t")[: self.n_traits]
+                )
             return self._cache["best_nlp"]
         return self._pull("batch_best_nlp")[: self.n_traits]
 
@@ -303,14 +324,9 @@ class BatchView:
                         "reconstruct the nlp tile"
                     )
                 t_np = self.t
-                self._cache["nlp"] = (
-                    _stats.refine_neglog10p(
-                        t_np.ravel(), float(self.dof),
-                        width=_stats.REFINE_WIDTH,
-                    )
-                    .astype(np.float32)
-                    .reshape(t_np.shape)
-                )
+                self._cache["nlp"] = self.refine(
+                    t_np.ravel(), width=_stats.REFINE_WIDTH
+                ).reshape(t_np.shape)
             return self._cache["nlp"]
         return self._pull("nlp")[: self.m_batch]
 
@@ -343,7 +359,12 @@ class BatchView:
     def t_probe(self, rows: int) -> np.ndarray:
         if "t" in self._cache:  # tile already on host (a hit pulled it)
             return self._cache["t"][: min(self.m_batch, rows), 0]
-        return np.asarray(self._out["t"][: min(self.m_batch, rows), 0])
+        with self.span("pull"):
+            return np.asarray(self._out["t"][: min(self.m_batch, rows), 0])
+
+
+def _no_span(name: str):
+    return contextlib.nullcontext()
 
 
 class ResultSink:

@@ -244,7 +244,10 @@ def t2_screen_threshold(threshold_nlp: float, dof: float) -> float | None:
     if not (target > 0.0) or not (dof > 0.0):
         return None
 
-    f = jax.jit(lambda t2: neglog10_p_from_t(jnp.sqrt(t2), dof))
+    def gwas_screen_probe(t2):
+        return neglog10_p_from_t(jnp.sqrt(t2), dof)
+
+    f = jax.jit(gwas_screen_probe)
 
     def nlp32(t2: float) -> float:
         return float(f(jnp.float32(t2)))
@@ -287,11 +290,36 @@ def _refine_exe(length: int, dof: float):
     shape or inside a differently-fused program can differ in the last
     f32 bit — so every emitted -log10 p must come out of *one* compiled
     program.  This cache is that program."""
-    return jax.jit(lambda t: neglog10_p_from_t(t, dof))
+    def gwas_refine(t):
+        return neglog10_p_from_t(t, dof)
+
+    return jax.jit(gwas_refine)
+
+
+class RefineTally:
+    """Counts one consumer's refine work: executable ``launches``, the
+    ``lanes`` they evaluated (padding included), and the launches whose
+    output landed on a device other than ``home`` (``foreign``).  ``home``
+    None counts none as foreign."""
+
+    __slots__ = ("home", "launches", "lanes", "foreign")
+
+    def __init__(self, home=None):
+        self.home = home
+        self.launches = 0
+        self.lanes = 0
+        self.foreign = 0
+
+    def count(self, out: jax.Array) -> None:
+        self.launches += 1
+        self.lanes += int(out.shape[0])
+        if self.home is not None and self.home not in out.devices():
+            self.foreign += 1
 
 
 def refine_neglog10p(
-    t_values: np.ndarray, dof: float, *, width: int | None = None
+    t_values: np.ndarray, dof: float, *, width: int | None = None,
+    tally: RefineTally | None = None,
 ) -> np.ndarray:
     """Canonical exact-tail refine (DESIGN.md §13).
 
@@ -302,13 +330,19 @@ def refine_neglog10p(
     fallback, the dense audit mode, and the full-tile reconstruction all
     feed slot-identical chunks to one executable and produce bit-identical
     values for the same t.  Padding lanes (t=0) map to nlp=0 and are
-    sliced off.
+    sliced off.  ``tally`` counts every launch.
     """
     flat = np.ascontiguousarray(np.asarray(t_values, np.float32).ravel())
     dof = float(dof)
+
+    def launch(exe, x: np.ndarray) -> np.ndarray:
+        out = exe(jnp.asarray(x))
+        if tally is not None:
+            tally.count(out)
+        return np.asarray(out)
+
     if width is None:
-        exe = _refine_exe(int(flat.shape[0]), dof)
-        return np.asarray(exe(jnp.asarray(flat)))
+        return launch(_refine_exe(int(flat.shape[0]), dof), flat)
     width = int(width)
     k = int(flat.shape[0])
     n_chunks = max(1, -(-k // width))
@@ -316,8 +350,7 @@ def refine_neglog10p(
     buf[:k] = flat
     exe = _refine_exe(width, dof)
     out = np.concatenate(
-        [np.asarray(exe(jnp.asarray(buf[i * width:(i + 1) * width])))
-         for i in range(n_chunks)]
+        [launch(exe, buf[i * width:(i + 1) * width]) for i in range(n_chunks)]
     )
     return out[:k]
 

@@ -140,4 +140,5 @@ def build_gwas_dot(
         ],
         scratch_shapes=[pltpu.VMEM((block_m, block_p), jnp.float32)],
         interpret=interpret,
+        name="gwas_dot",
     )

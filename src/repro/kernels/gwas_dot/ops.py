@@ -155,10 +155,11 @@ def decode_packed_device(plink_packed, *, n_samples: int):
     same compiled artifacts they were under dense staging, which is what
     makes packed staging bitwise-neutral (§17).
     """
-    c = (plink_packed[:, :, None].astype(jnp.int32) >> (2 * jnp.arange(4))) & 0b11
-    c = c.reshape(plink_packed.shape[0], -1)[:, :n_samples]
-    dose = (2 - c + (c >> 1)).astype(jnp.float32)
-    return jnp.where(c == 0b01, jnp.float32(-9.0), dose)
+    with jax.named_scope("gwas.device_decode"):
+        c = (plink_packed[:, :, None].astype(jnp.int32) >> (2 * jnp.arange(4))) & 0b11
+        c = c.reshape(plink_packed.shape[0], -1)[:, :n_samples]
+        dose = (2 - c + (c >> 1)).astype(jnp.float32)
+        return jnp.where(c == 0b01, jnp.float32(-9.0), dose)
 
 
 @functools.partial(
@@ -179,24 +180,26 @@ def repack_plink_tiled_device(
     if block_n % 4:
         raise ValueError("block_n must be a multiple of 4")
     m = plink_packed.shape[0]
-    c = (plink_packed[:, :, None].astype(jnp.uint8) >> (2 * jnp.arange(4, dtype=jnp.uint8))) & 0b11
-    c = c.reshape(m, -1)[:, :n_samples]
-    n_pad = n_samples + (-n_samples) % block_n
-    m_pad = m + (-m) % block_m
-    c = jnp.pad(
-        c,
-        ((0, m_pad - m), (0, n_pad - n_samples)),
-        constant_values=np.uint8(0b01),
-    )
-    quarter = block_n // 4
-    tiles = c.reshape(m_pad, n_pad // block_n, 4, quarter)
-    packed = (
-        tiles[:, :, 0, :]
-        | (tiles[:, :, 1, :] << 2)
-        | (tiles[:, :, 2, :] << 4)
-        | (tiles[:, :, 3, :] << 6)
-    )
-    return packed.reshape(m_pad, n_pad // 4).astype(jnp.uint8)
+    with jax.named_scope("gwas.device_decode"):
+        c = (plink_packed[:, :, None].astype(jnp.uint8)
+             >> (2 * jnp.arange(4, dtype=jnp.uint8))) & 0b11
+        c = c.reshape(m, -1)[:, :n_samples]
+        n_pad = n_samples + (-n_samples) % block_n
+        m_pad = m + (-m) % block_m
+        c = jnp.pad(
+            c,
+            ((0, m_pad - m), (0, n_pad - n_samples)),
+            constant_values=np.uint8(0b01),
+        )
+        quarter = block_n // 4
+        tiles = c.reshape(m_pad, n_pad // block_n, 4, quarter)
+        packed = (
+            tiles[:, :, 0, :]
+            | (tiles[:, :, 1, :] << 2)
+            | (tiles[:, :, 2, :] << 4)
+            | (tiles[:, :, 3, :] << 6)
+        )
+        return packed.reshape(m_pad, n_pad // 4).astype(jnp.uint8)
 
 
 @functools.partial(

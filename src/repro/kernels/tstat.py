@@ -39,6 +39,7 @@ def _tstat_padded(r, *, dof, block_m, block_p, interpret):
         out_specs=pl.BlockSpec((block_m, block_p), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, p), jnp.float32),
         interpret=interpret,
+        name="gwas_tstat",
     )(r)
 
 
@@ -106,6 +107,7 @@ def _screen_padded(r, *, dof, t2_screen, block_m, block_p, interpret):
             jax.ShapeDtypeStruct((gm * _COUNT_TILE[0], gp * _COUNT_TILE[1]), jnp.int32),
         ],
         interpret=interpret,
+        name="gwas_screen_compact",
     )(r)
 
 

@@ -32,8 +32,8 @@ from typing import Any
 
 import numpy as np
 
-from repro.api.metrics import CellTiming, ScanMetrics
-from repro.api.session import ScanSession, _live_cell
+from repro.api.metrics import ScanMetrics, span
+from repro.api.session import ScanSession, _cell_timing, _live_cell
 from repro.api.writers import TsvWriter
 from repro.runtime.workqueue import WorkQueue
 from repro.serve.fair import DeficitRoundRobin
@@ -279,16 +279,16 @@ class ServeExecutor:
             if owner:
                 once = req.decoded[batch.index] = _Once()
         if owner:
-            t0 = time.perf_counter()
             try:
                 prep = req.prepared
-                hb = prep.engine.prepare_batch(
-                    prep.study.source, batch, prep.ctx
-                )
+                with span("decode") as took:
+                    hb = prep.engine.prepare_batch(
+                        prep.study.source, batch, prep.ctx
+                    )
             except BaseException as e:  # noqa: BLE001 — waiters must wake
                 once.fail(e)
                 raise
-            once.set((hb, time.perf_counter() - t0))
+            once.set((hb, took.seconds))
             return once.get()
         hb, _ = once.get(timeout=600.0)
         return hb, 0.0                 # decode cost attributed to the owner
@@ -301,7 +301,6 @@ class ServeExecutor:
         hb, decode_s = self._host_batch(req, batch)
         slot = self.registry.acquire_slot(req.state_key, slot_idx)
         try:
-            t0 = time.perf_counter()
             # Per-slot staged memo: consecutive cells of one request's
             # batch reuse the H2D copy (the slot belongs to this worker
             # alone, so the attribute is single-threaded).
@@ -309,32 +308,25 @@ class ServeExecutor:
             if memo is not None and memo[0] == (req.request_id, batch.index):
                 dev_args, stage_s = memo[1], 0.0
             else:
-                ts = time.perf_counter()
-                dev_args = slot.stage(hb)
-                stage_s = time.perf_counter() - ts
+                with slot.span("stage") as took:
+                    dev_args = slot.stage(hb)
+                stage_s = took.seconds
                 slot._serve_staged = ((req.request_id, batch.index), dev_args)
-            out = slot.step(*dev_args, slot.panel_block(batch, blk))
-            jax.block_until_ready(out)
-            t1 = time.perf_counter()
-            cell = _live_cell(hb, out, blk, prep.config, prep.dof)
-            t2 = time.perf_counter()
+            panel = slot.panel_block(batch, blk)
+            with slot.span("dispatch") as dispatch:
+                out = slot.step(*dev_args, panel)
+            with slot.span("fence") as fence:
+                jax.block_until_ready(out)
+            cell, extract_s = _live_cell(hb, out, blk, prep.config, prep.dof, slot)
         finally:
             self.registry.release_slot(req.state_key, slot_idx)
         with req.lock:
             req.cells_left[batch.index] -= 1
             if req.cells_left[batch.index] <= 0:
                 req.decoded.pop(batch.index, None)   # free host batch early
-        timing = CellTiming(
-            batch_index=batch.index,
-            block_index=blk.index,
-            n_markers=cell.n_markers,
-            n_traits=cell.n_traits,
-            wall_s=t2 - t0,
-            step_s=t1 - t0,
-            extract_s=t2 - t1,
-            decode_s=decode_s,
-            stage_s=stage_s,
-            device=label,
+        timing = _cell_timing(
+            cell, step_s=dispatch.seconds + fence.seconds, extract_s=extract_s,
+            decode_s=decode_s, stage_s=stage_s, h2d_bytes=0, device=label,
         )
         return cell, timing
 
