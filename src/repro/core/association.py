@@ -38,6 +38,7 @@ __all__ = [
     "assoc_from_standardized",
     "assoc_batch",
     "plan_sparse_epilogue",
+    "compact_survivors",
     "sparse_epilogue_outputs",
 ]
 
@@ -299,6 +300,42 @@ def plan_sparse_epilogue(
     return SparseEpilogue(float(threshold_nlp), float(t2), cap)
 
 
+_CHUNK = 128  # lanes per chunk of compact_survivors: one TPU vector row
+
+
+def compact_survivors(keep: jax.Array, capacity: int) -> tuple[jax.Array, jax.Array]:
+    """First-K compaction of a boolean screen without a scatter.
+
+    Returns ``(hit_idx, screen_count)``: ``hit_idx`` (capacity,) int32 holds
+    the row-major flat indices of the first ``capacity`` true lanes in
+    ascending order, -1 padded — bitwise ``jnp.nonzero(keep.ravel(),
+    size=capacity, fill_value=-1)[0]`` — and ``screen_count`` () int32 is the
+    exact number of true lanes, even past ``capacity``.
+
+    ``nonzero`` lowers to a full-length cumsum plus a scatter-add of every
+    lane into ``capacity`` bins, which the TPU serialises.  Here the flat
+    screen is cut into 128-lane chunks (the tail zero-padded) and reduced to
+    one count per chunk; a binary search of the counts' inclusive cumsum
+    finds the chunk holding each output slot's survivor, and a cumsum over
+    only those ``capacity`` gathered chunks finds its lane.  The cost does
+    not depend on how many lanes survive (DESIGN.md §13).
+    """
+    flat = keep.ravel()
+    n_chunks = -(-flat.size // _CHUNK)
+    chunks = jnp.pad(flat, (0, n_chunks * _CHUNK - flat.size)).reshape(n_chunks, _CHUNK)
+    counts = jnp.sum(chunks, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)                      # survivors up to and with chunk j
+    screen_count = ends[-1]
+    k = jnp.arange(capacity, dtype=jnp.int32)      # output slot = survivor rank
+    chunk = jnp.searchsorted(ends, k, side="right", method="scan_unrolled")
+    chunk = jnp.minimum(chunk, n_chunks - 1).astype(jnp.int32)
+    rank = k - (ends[chunk] - counts[chunk])       # the survivor's rank in its chunk
+    running = jnp.cumsum(chunks[chunk], axis=1, dtype=jnp.int32)
+    lane = jnp.sum(running <= rank[:, None], axis=1, dtype=jnp.int32)
+    idx = jnp.where(k < screen_count, chunk * _CHUNK + lane, -1)
+    return idx, screen_count
+
+
 def sparse_epilogue_outputs(
     r: jax.Array,
     t: jax.Array,
@@ -328,9 +365,10 @@ def sparse_epilogue_outputs(
         screen_count     () int32 — total screened lanes; > capacity means
                          the buffer overflowed (host fallback)
 
-    ``screen`` optionally supplies ``(hit_idx, screen_count)`` from a fused
-    kernel (``kernels.tstat.screen_compact``) instead of the XLA
-    nonzero-gather; the compaction layout is identical either way.
+    ``screen`` optionally supplies ``(hit_idx, screen_count)`` from the fused
+    screen kernel (``kernels.tstat.screen_compact``); both compact through
+    ``compact_survivors``, so the layout is identical either way.  With no
+    survivors every slot is -1.
 
     Runs under the ``gwas.epilogue`` scope: the winners under
     ``gwas.epilogue.best``, the screen and compaction under
@@ -348,27 +386,15 @@ def sparse_epilogue_outputs(
             best_t = jnp.take_along_axis(t, best_row[None, :], axis=0)[0]
         with jax.named_scope("gwas.epilogue.compact"):
             if screen is None:
-                keep = t2.ravel() >= plan.t2_screen
-                screen_count = jnp.sum(keep).astype(jnp.int32)
-                # nonzero lowers to a full-length serial cumsum on XLA CPU —
-                # by far the most expensive op in the epilogue.  Almost every
-                # tile of a genome scan has zero survivors, so gate the
-                # compaction on the cheap reduction: the empty branch emits
-                # exactly what nonzero(fill_value=-1) would (all -1), so
-                # emitted bits are unchanged in every case.
-                idx = jax.lax.cond(
-                    screen_count > 0,
-                    lambda: jnp.nonzero(
-                        keep, size=plan.capacity, fill_value=-1
-                    )[0].astype(jnp.int32),
-                    lambda: jnp.full((plan.capacity,), -1, jnp.int32),
-                )
+                idx, screen_count = compact_survivors(t2 >= plan.t2_screen, plan.capacity)
             else:
                 idx, screen_count = screen
             slot = idx >= 0
-            safe = jnp.maximum(idx, 0)
-            hit_t = jnp.where(slot, t.ravel()[safe], 0.0)
-            hit_r = jnp.where(slot, r.ravel()[safe], 0.0)
+            # (row, col) gathers read the tiles in place; a gather from the
+            # flat view would relayout both whole tiles first on the TPU.
+            row, col = jnp.divmod(jnp.maximum(idx, 0), t.shape[1])
+            hit_t = jnp.where(slot, t[row, col], 0.0)
+            hit_r = jnp.where(slot, r[row, col], 0.0)
     return {
         "batch_best_row": best_row,
         "batch_best_t": best_t,

@@ -814,9 +814,9 @@ def build_lmm_step(
 
     ``sparse_epilogue`` — see ``build_dense_step``.  With
     ``epilogue="fused"`` the t^2 screen additionally fuses into the Pallas
-    t-statistic pass (``kernels.tstat.screen_compact``): Eq. 3, the screen
-    compare, and the per-block survivor counts run in one kernel; the exact
-    CF then touches only the compacted lanes.
+    t-statistic pass (``kernels.tstat.screen_compact``): Eq. 3 and the
+    screen compare run in one kernel; the exact CF then touches only the
+    compacted lanes.
     """
     if epilogue not in ("dense", "fused"):
         raise ValueError(f"unknown lmm epilogue {epilogue!r}")

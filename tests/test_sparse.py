@@ -84,6 +84,57 @@ def test_plan_capacity_rounds_to_simd_multiple():
     assert A.plan_sparse_epilogue(7.301, 998.0, capacity=4096).capacity == 4096
 
 
+# ------------------------------------------------------------- compaction
+
+
+def _screen(shape, lanes=(), density=0.0, seed=0):
+    keep = np.random.default_rng(seed).random(shape) < density
+    keep.ravel()[list(lanes)] = True
+    return keep
+
+
+@pytest.mark.parametrize(
+    "keep, capacity",
+    [
+        (_screen((7, 45), density=0.2), 128),              # 315 lanes, ragged tail
+        (_screen((3, 5), density=0.5), 64),                # one partial chunk
+        (_screen((16, 256)), 64),                          # no survivors
+        (_screen((9, 131), lanes=[9 * 131 - 1]), 64),      # the very last lane
+        (_screen((4, 300), lanes=range(100, 400)), 512),   # full chunks, a long run
+        (np.ones((6, 100), bool), 640),                    # every lane survives
+        (_screen((64, 96), density=0.5, seed=1), 256),     # overflow past capacity
+        (_screen((8, 16), density=0.7, seed=2),
+         A.plan_sparse_epilogue(1.0, 998.0, cell_area=128).capacity),  # clamped
+    ],
+    ids=["ragged", "sub_chunk", "empty", "last_lane", "long_run", "all",
+         "overflow", "clamped"],
+)
+def test_compact_survivors_matches_nonzero(keep, capacity):
+    """The scatter-free compaction is ``np.nonzero`` order, first-K, -1
+    padded, bit for bit; the count stays exact past capacity."""
+    idx, count = A.compact_survivors(jnp.asarray(keep), capacity)
+    want = np.full(capacity, -1, np.int32)
+    nz = np.nonzero(keep.ravel())[0][:capacity]
+    want[: nz.size] = nz
+    assert idx.dtype == jnp.int32 and count.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(idx), want)
+    assert int(count) == int(keep.sum())
+
+
+def test_sparse_epilogue_has_no_scatter():
+    """The epilogue's compaction lowers without a scatter: ``jnp.nonzero``
+    would add a scatter-add of every lane of the tile into ``capacity``
+    bins, which the TPU serialises."""
+    import jax
+
+    plan = A.plan_sparse_epilogue(7.301, 998.0)
+    tile = jax.ShapeDtypeStruct((1024, 2048), jnp.float32)
+    hlo = jax.jit(
+        lambda r, t: A.sparse_epilogue_outputs(r, t, 998.0, plan)
+    ).lower(tile, tile).as_text()
+    assert "gather" in hlo and "scatter" not in hlo
+
+
 def test_tie_breaks_match_dense_argmax_rule():
     """Exact t^2 ties (plus nlp plateaus) resolve to the first index in
     both paths — the redefined winner rule both share.  The step emits the
