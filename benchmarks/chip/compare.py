@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``.
 
 What the writer received for the window's cells is held against the plain
-reference (``reference.Reference``).  Six numbers, each against the limit
-its configuration file states:
+reference of the configuration's deployment (``reference(cohort, config)``
+of ``deployments/<name>.py``), which is asked about genome marker indices
+as the scan reports them.  Six numbers, each against the limit its
+configuration file states:
 
   r_gap          max |r - r_ref| over every hit row of every window cell
   nlp_gap        max |nlp - nlp_ref| / nlp_ref over every hit row, and over
@@ -64,7 +66,6 @@ def compare(answers: list[Answer], ref, *, n_traits: int, batch_markers: int,
             check_cells: int, rng: np.random.Generator,
             limits: dict) -> tuple[dict[str, float], int]:
     """The six numbers, and how many cells failed any of them."""
-    distinct = ref.pool.shape[0]
     band = 2.0 * limits["nlp_gap"]
     per_cell = [dict.fromkeys(ORDER, 0.0) for _ in answers]
 
@@ -84,7 +85,7 @@ def compare(answers: list[Answer], ref, *, n_traits: int, batch_markers: int,
             whole_cells.append(i)
         seen.add((a.lo, a.t_lo))
 
-    # Every hit row of every cell, against float64 r of its (pool row, trait).
+    # Every hit row of every cell, against the reference's r of its (marker, trait).
     rows, stats, owner = [], [], []
     for i, a in enumerate(answers):
         h = np.asarray(a.hits, np.int64).reshape(-1, 2)
@@ -96,9 +97,7 @@ def compare(answers: list[Answer], ref, *, n_traits: int, batch_markers: int,
     hits = np.concatenate(rows) if rows else np.zeros((0, 2), np.int64)
     if len(hits):
         hit_stats, owner = np.concatenate(stats), np.concatenate(owner)
-        pairs, inverse = np.unique(
-            np.stack([hits[:, 0] % distinct, hits[:, 1]], 1), axis=0, return_inverse=True)
-        r_ref = ref.r_pairs(pairs[:, 0], pairs[:, 1])[inverse.ravel()]
+        r_ref = ref.r_pairs(hits[:, 0], hits[:, 1])
         nlp_ref = ref.nlp(ref.t(r_ref))
         r_gap = np.abs(hit_stats[:, 0] - r_ref)
         nlp_gap = np.abs(hit_stats[:, 2] - nlp_ref) / np.maximum(nlp_ref, 1.0)
@@ -114,14 +113,10 @@ def compare(answers: list[Answer], ref, *, n_traits: int, batch_markers: int,
     hit_set = {(int(m), int(t)) for m, t in hits}
     t_line = t_at(ref, threshold * (1.0 + band))
     cols = np.arange(len(check_traits))
-    t_by_range: dict[tuple[int, int], np.ndarray] = {}
     picked = rng.choice(whole_cells, size=min(check_cells, len(whole_cells)), replace=False)
     for i in sorted(picked):
         a = answers[i]
-        key = (a.lo % distinct, a.hi - a.lo)
-        if key not in t_by_range:
-            t_by_range[key] = ref.t(ref.r_block((a.lo + np.arange(a.hi - a.lo)) % distinct, y))
-        t = t_by_range[key]                                   # (markers, checked traits)
+        t = ref.t(ref.r_block(a.lo + np.arange(a.hi - a.lo), y))   # (markers, checked traits)
         prog = np.clip(np.asarray(a.best_row, np.int64)[check_traits], 0, len(t) - 1)
         nlp_top = ref.nlp(t[np.argmax(np.abs(t), axis=0), cols])
         nlp_prog = ref.nlp(t[prog, cols])

@@ -5,8 +5,9 @@
     python3 benchmarks/chip/control.py --workload ukb23k_fused.p20480 --seeds 1 2 3 \
         --program bf16
 
-Without ``--program``, the reference one precision step down
-(``reference.LowerPrecision``) is put in the program's place: it answers
+Without ``--program``, the reference one precision step down (the
+``control(cohort, config)`` of the configuration's deployment module) is
+put in the program's place: it answers
 the cells a window would hold, one per distinct stretch of the pool, and
 those answers go through the same comparison as a run's.  With
 ``--program bf16`` the program itself runs the cell with its own bf16 GEMM
@@ -35,13 +36,12 @@ def control_answers(low, cells: list[tuple[int, int]], n_traits: int, threshold:
     """What the lower-precision reference answers for each (lo, hi) cell."""
     from compare import Answer, t_at
 
-    distinct = low.pool.shape[0]
     y = low.panel(np.arange(n_traits))
     screen = t_at(low, threshold) * 0.98
     cols = np.arange(n_traits)
     out = []
     for lo, hi in cells:
-        r = low.r_block((lo + np.arange(hi - lo)) % distinct, y)
+        r = low.r_block(lo + np.arange(hi - lo), y)
         t = low.t(r)
         best_row = np.argmax(t * t, axis=0)
         best_nlp = low.nlp(t[best_row, cols])
@@ -57,22 +57,20 @@ def control_answers(low, cells: list[tuple[int, int]], n_traits: int, threshold:
 def reference_control(cell, seed: int, n_cells: int):
     """(numbers, failed cells, correct) of the lower-precision reference."""
     import compare
-    from cohort import make_cohort
     from harness import check_sample
-    from reference import LowerPrecision, Reference
 
     config, traffic, scan = cell.config, cell.traffic, cell.config["scan"]
-    cohort = make_cohort(config, traffic, seed)
-    args = (cohort.pool, cohort.phenotypes, cohort.covariates, config["n_samples"])
+    deployment = cell.deployment
+    cohort = deployment.make_cohort(config, traffic, seed)
     b = scan["batch_markers"]
     first = config["warmup_cells_per_device"]
     cells = [(k * b, (k + 1) * b) for k in range(first, first + n_cells)]
-    answers = control_answers(LowerPrecision(*args), cells, traffic["n_traits"],
+    answers = control_answers(deployment.control(cohort, config), cells, traffic["n_traits"],
                               scan["hit_threshold_nlp"])
     limits = config["limits"]
     numbers, failed = compare.compare(
-        answers, Reference(*args), n_traits=traffic["n_traits"], batch_markers=b,
-        n_markers=config["n_markers"], threshold=scan["hit_threshold_nlp"],
+        answers, deployment.reference(cohort, config), n_traits=traffic["n_traits"],
+        batch_markers=b, n_markers=config["n_markers"], threshold=scan["hit_threshold_nlp"],
         limits=limits, **check_sample(seed, traffic))
     return numbers, failed, compare.verdict(numbers, limits)
 

@@ -2,21 +2,24 @@
 
 Everything a cell needs is found by name: the workload's entry in
 ``BENCHMARK.json`` names a configuration (its ``file``) and a traffic mix
-(``traffic/<mix>.json``), and every metric the run reports is read by
-``metrics/<metric>.py``.  A later cell, configuration or metric is a new
-file and a new entry; nothing here changes.
+(``traffic/<mix>.json``); the configuration names its deployment module
+(``deployments/<name>.py``, key ``"deployment_module"``, ``ols`` where the
+key is absent); and every metric the run reports is read by
+``metrics/<metric>.py``.  A later cell, configuration, deployment or
+metric is a new file and a new entry; nothing here changes.
 
-One run:
+One run, each step through the deployment module where it names one:
 
-  set-up   make the cohort on the device from the seed, bind it with
-           ``Study.from_arrays`` over a ``VirtualGenome``, ``plan(...)``,
-           ``prepare()``, open the session and a ``TsvWriter`` in a
-           temporary directory, and pull the first cells (warm-up) until
-           every device slot has delivered ``warmup_cells_per_device``
+  set-up   ``make_cohort`` from the seed, ``bind`` it to a ``Study``,
+           ``plan(**plan_kwargs(...))``, ``prepare()``, open the session
+           and a ``TsvWriter`` in a temporary directory, and pull the first
+           cells (warm-up) until every device slot has delivered
+           ``warmup_cells_per_device``
   window   pull cells from ``ScanSession.events()`` and write each until
            ``seconds`` have passed; only these cells count
   check    read the device's memory peak, tear the session down, free it,
-           and compare what the writer received with the plain reference
+           and compare what the writer received with the deployment's
+           plain ``reference``
 """
 from __future__ import annotations
 
@@ -32,15 +35,15 @@ import tempfile
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from types import ModuleType
 
 import numpy as np
 
 import compare
 import trace_reduce
+import trace_scopes
 import work
-from cohort import make_cohort, seed_sequence
-from genome import VirtualGenome
-from reference import Reference
+from cohort import seed_sequence
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -51,6 +54,8 @@ COMPILE_EVENTS = (
     "/jax/core/compile/backend_compile_duration",
 )
 WARMUP_CELL_CAP = 256
+DEPLOYMENT_KEY = "deployment_module"
+DEFAULT_DEPLOYMENT = "ols"
 
 
 class NoChip(RuntimeError):
@@ -66,6 +71,7 @@ class Cell:
     chips: int
     config: dict
     traffic: dict
+    deployment: ModuleType | None = None    # deployments/<name>.py, see load_deployment
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -83,7 +89,9 @@ def find_cell(bench: dict, name: str, *, root: str = ROOT, bench_dir: str = HERE
         config = json.load(f)
     with open(os.path.join(bench_dir, "traffic", wl["traffic"] + ".json")) as f:
         traffic = json.load(f)
-    return Cell(name=name, chips=int(wl["chips"]), config=config, traffic=traffic)
+    deployment = load_deployment(config.get(DEPLOYMENT_KEY, DEFAULT_DEPLOYMENT), bench_dir)
+    return Cell(name=name, chips=int(wl["chips"]), config=config, traffic=traffic,
+                deployment=deployment)
 
 
 def metrics_of(bench: dict, cell: str, kind: str) -> list[dict]:
@@ -91,13 +99,38 @@ def metrics_of(bench: dict, cell: str, kind: str) -> list[dict]:
     return [m for m in bench[kind] if cell in m.get("workloads", [cell])]
 
 
-def load_reader(name: str, bench_dir: str = HERE):
-    """``read(run) -> float | None`` of ``metrics/<name>.py``."""
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"chip_metric_{name}", path)
+def _load(kind: str, name: str, bench_dir: str) -> ModuleType:
+    """``<bench_dir>/<kind>/<name>.py``, loaded by path."""
+    path = os.path.join(bench_dir, kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(f"chip_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(name: str, bench_dir: str = HERE):
+    """``read(run) -> float | None`` of ``metrics/<name>.py``."""
+    return _load("metrics", name, bench_dir).read
+
+
+def load_deployment(name: str, bench_dir: str = HERE) -> ModuleType:
+    """``deployments/<name>.py``: how a configuration's data is made, bound,
+    planned, checked and counted.  It provides
+
+      make_cohort(config, traffic, seed)   the cell's data, the same for the same seed
+      bind(cohort, config)                 the ``repro.api.Study`` the scan runs on
+      plan_kwargs(scan, spill_dir)         keywords of ``Study.plan``
+      reference(cohort, config)            the plain reference ``compare.py`` holds
+                                           the answers against
+      control(cohort, config)              that reference one precision step down
+      least_seconds(markers, samples, traits, peak)
+                                           (least time of a cell's work on a chip with
+                                           ``peak``, "compute" or "memory")
+
+    A reference (and the control) answers ``r_pairs(markers, traits)``,
+    ``r_block(markers, y)``, ``panel(traits)``, ``t(r)`` and ``nlp(t)``,
+    where ``markers`` are genome marker indices as the scan reports them."""
+    return _load("deployments", name, bench_dir)
 
 
 # ------------------------------------------------------ spans and compiles
@@ -160,6 +193,7 @@ class Run:
     scan_before: dict = field(default_factory=dict)   # ScanMetrics at window open
     scan_after: dict = field(default_factory=dict)    # ... and close
     trace: trace_reduce.Reduced | None = None
+    trace_path: str | None = None       # the run's ``.xplane.pb`` (``trace_scopes.of``)
     peak: dict | None = None            # the chip's published peaks
 
     @property
@@ -172,36 +206,16 @@ class Run:
 
 
 def scan_snapshot(metrics) -> dict:
+    """The session's ``ScanMetrics`` at one moment.  ``counters`` is
+    ``ScanMetrics.counters()`` (``refine_launches``, ``hits``, ... over live
+    cells) and ``spans`` is ``span_totals()`` (``gwas.<name>`` without the
+    prefix -> (seconds, count)), so a reader can take any of them as a
+    window delta."""
     s = metrics.summary()
     return {"decode_s": metrics.decode_s_total, "extract_s": s["extract_s"],
             "markers": metrics.markers_done(), "cells": s["live_cells"],
-            "h2d_bytes_per_marker": metrics.h2d_bytes_per_marker()}
-
-
-def plan_kwargs(scan: dict, spill_dir: str) -> dict:
-    from repro.api import ExecSpec, GridSpec, IOSpec
-    from repro.core.association import AssocOptions
-
-    return dict(
-        engine=scan["engine"],
-        grid=GridSpec(batch_markers=scan["batch_markers"], trait_block=scan["trait_block"],
-                      block_m=scan["block_m"], block_n=scan["block_n"],
-                      block_p=scan["block_p"],
-                      panel_resident_blocks=scan["panel_resident_blocks"]),
-        io=IOSpec(prefetch_depth=scan["prefetch_depth"], io_workers=scan["io_workers"],
-                  spill_dir=spill_dir, hit_spill_rows=scan["hit_spill_rows"],
-                  genotype_staging=scan["genotype_staging"],
-                  packed_cache_mb=scan["packed_cache_mb"]),
-        executor=ExecSpec(devices=scan["devices"], placement=scan["placement"],
-                          lease_batches=scan["lease_batches"],
-                          slot_prefetch=scan["slot_prefetch"],
-                          autotune_lease=scan["autotune_lease"]),
-        options=AssocOptions(dof_mode=scan["dof_mode"], precision=scan["precision"]),
-        hit_threshold_nlp=scan["hit_threshold_nlp"],
-        input_dtype=scan["input_dtype"],
-        sparse_epilogue=scan["sparse_epilogue"],
-        hit_capacity=scan["hit_capacity"],
-    )
+            "h2d_bytes_per_marker": metrics.h2d_bytes_per_marker(),
+            "counters": metrics.counters(), "spans": metrics.span_totals()}
 
 
 def check_sample(seed: int, traffic: dict) -> dict:
@@ -220,6 +234,26 @@ def _warm_up(events, writer, session, *, slots: int, per_slot: int) -> None:
         writer.write(next(events))
     raise RuntimeError(f"warm-up did not reach {per_slot} cells on each of {slots} "
                        f"slots within {WARMUP_CELL_CAP} cells")
+
+
+def trace_file(trace_dir: str) -> str | None:
+    """The ``.xplane.pb`` the profiler wrote under ``trace_dir``, if any."""
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    return found[0] if found else None
+
+
+def reduce_trace(path: str, devices: list[int]):
+    """(``trace_reduce``'s reduction, the result line's ``breakdown``) of the
+    trace at ``path``: the device ops that took the most time, and the
+    longest idle gaps labelled by the program's own spans
+    (``trace_scopes.gap_labels``).  (None, None) where the trace holds no
+    window or no device operation."""
+    reduced = trace_reduce.reduce(trace_reduce.load(path), devices=devices)
+    if reduced is None:
+        return None, None
+    gaps = trace_scopes.gap_labels(trace_scopes.load_once(path), devices)
+    return reduced, {"device_ops": [list(x) for x in reduced.device_ops],
+                     "idle_gaps": [list(x) for x in gaps]}
 
 
 def _peak_bytes(devices) -> int:
@@ -259,21 +293,21 @@ def _run(cell, bench, seed, seconds, trace, *, started, work_dir, bench_dir, dev
          peak, log) -> dict:
     import jax
 
-    from repro.api import Study, TsvWriter
+    from repro.api import TsvWriter
 
     config, traffic, scan = cell.config, cell.traffic, cell.config["scan"]
+    deployment = cell.deployment
     dev, used = devices[0], devices[:scan["devices"]]
     spans, compiles = Spans(), CompileLog()
     trace_dir = os.path.join(work_dir, "trace")
     events = writer = None
     try:
         with spans("setup.data"):
-            cohort = make_cohort(config, traffic, seed)
-        source = VirtualGenome(cohort.pool, config["n_samples"], config["n_markers"])
+            cohort = deployment.make_cohort(config, traffic, seed)
         with spans("setup.bind"):
-            study = Study.from_arrays(source, cohort.phenotypes, cohort.covariates)
+            study = deployment.bind(cohort, config)
         with spans("setup.prepare"):
-            plan = study.plan(**plan_kwargs(scan, os.path.join(work_dir, "out")))
+            plan = study.plan(**deployment.plan_kwargs(scan, os.path.join(work_dir, "out")))
             plan.prepare()
         session = plan.run(resume=False)
         writer = TsvWriter(os.path.join(work_dir, "out"), spill_rows=scan["hit_spill_rows"])
@@ -313,19 +347,17 @@ def _run(cell, bench, seed, seconds, trace, *, started, work_dir, bench_dir, dev
             writer.abort()
         compiles.close()
     executor = session.executor_info
-    del events, writer, session, plan, study, source
+    del events, writer, session, plan, study
     gc.collect()
 
-    reduced = None
-    if trace:
-        found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
-        if found:
-            reduced = trace_reduce.reduce(trace_reduce.load(found[0]),
-                                          devices=[d.id for d in used])
+    reduced = breakdown = None
+    path = trace_file(trace_dir) if trace else None
+    if path is not None:
+        reduced, breakdown = reduce_trace(path, [d.id for d in used])
 
     run = Run(cell=cell, spans=spans, compiles=compiles, started=started, window=(t0, t1),
               window_cells=sizes, scan_before=before, scan_after=after, trace=reduced,
-              peak=peak)
+              trace_path=path, peak=peak)
     metrics = {}
     for entry in metrics_of(bench, cell.name, "per_layer" if trace else "end_to_end"):
         value = load_reader(entry["name"], bench_dir)(run)
@@ -333,7 +365,7 @@ def _run(cell, bench, seed, seconds, trace, *, started, work_dir, bench_dir, dev
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
 
     t_ref = time.perf_counter()
-    ref = Reference(cohort.pool, cohort.phenotypes, cohort.covariates, config["n_samples"])
+    ref = deployment.reference(cohort, config)
     limits = config["limits"]
     numbers, failed = compare.compare(
         answers, ref, n_traits=traffic["n_traits"], batch_markers=scan["batch_markers"],
@@ -353,8 +385,7 @@ def _run(cell, bench, seed, seconds, trace, *, started, work_dir, bench_dir, dev
     if trace and reduced is not None:
         device["busy_s"] = reduced.mean_busy_s
         device["window_s"] = reduced.window_s
-        result["breakdown"] = {"device_ops": [list(x) for x in reduced.device_ops],
-                               "idle_gaps": [list(x) for x in reduced.idle_gaps]}
+        result["breakdown"] = breakdown
     result["executor"] = {k: executor.get(k) for k in ("kind", "devices", "autotune")} \
         if executor else None
     for k in compare.ORDER:

@@ -1,5 +1,6 @@
-"""A tiny copy of a benchmark layout for CPU tests: the real metric readers,
-a configuration cut to a few hundred samples, and one traffic mix."""
+"""A tiny copy of a benchmark layout for CPU tests: the real metric readers
+and deployment modules, a configuration cut to a few hundred samples, and
+one traffic mix."""
 from __future__ import annotations
 
 import json
@@ -20,8 +21,9 @@ def make_layout(root: str, *, engine: str = "dense") -> str:
     """Write ``root/BENCHMARK.json`` and a bench dir under ``root``; return it."""
     bench_dir = os.path.join(root, "bench")
     os.makedirs(os.path.join(bench_dir, "traffic"), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"), os.path.join(bench_dir, "metrics"),
-                    dirs_exist_ok=True)
+    for sub in ("metrics", "deployments"):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench_dir, sub),
+                        dirs_exist_ok=True, ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(BENCH, "configs", "ukb23k_fused.json")) as f:
         config = json.load(f)
     config.update(n_samples=512, n_covariates=3, n_markers=4096, distinct_markers=1024)

@@ -90,11 +90,16 @@ def test_a_fault_under_the_timed_path_is_not_correct(layout, monkeypatch, fault)
     assert not r["correct"] and r["failed"] > 0
 
 
-def test_the_control_is_not_correct(layout):
+def test_the_control_is_not_correct(layout, monkeypatch):
     root, bench_dir = layout
     cell = harness.find_cell(harness.load_benchmark(root), bench_tiny.CELL, root=root,
                              bench_dir=bench_dir)
+    made = []
+    real = cell.deployment.control
+    monkeypatch.setattr(cell.deployment, "control",
+                        lambda cohort, config: made.append(real(cohort, config)) or made[-1])
     for seed in (1, 2, 3):
         numbers, failed, correct = control.reference_control(cell, seed, 4)
         assert not correct and failed > 0
         assert numbers["nlp_gap"] > cell.config["limits"]["nlp_gap"]
+    assert len(made) == 3       # the control is the configuration's deployment's

@@ -157,15 +157,43 @@ def test_on_several_chips_each_gap_is_labelled_by_its_slot(trace):
 
 
 def test_the_existing_reduction_reads_as_before(tmp_path):
-    """trace_reduce on the same file: op sums not merged, bench labels."""
+    """trace_reduce on the same file: op sums not merged; its idle gaps are
+    the ones gap_labels labels."""
     red = tr.reduce(tr.load(_write(tmp_path / "t.xplane.pb")), devices=[0])
     assert red.busy_s[0] == pytest.approx(11.5 * US)
     assert red.idle_share == pytest.approx(1 - 11.5 / 20)
     ops = dict(red.device_ops)
     assert ops  # named by trace_reduce's own rule, each op's time summed
     assert sum(ops.values()) == pytest.approx(13.5 * US)    # 3 + 2 counted twice
-    assert [n for n, _ in red.idle_gaps] == ["bench.write", "bench.pull", "bench.pull",
-                                            "bench.pull"]
+    labelled = ts.gap_labels(ts.load(str(tmp_path / "t.xplane.pb")), [0])
+    assert sum(s for _, s in labelled) == pytest.approx(red.window_s - red.busy_s[0])
+
+
+def test_the_breakdown_labels_idle_gaps_by_the_programs_spans(tmp_path):
+    path = _write(tmp_path / "trace" / "plugins" / "profile" / "t" / "h.xplane.pb")
+    assert harness.trace_file(str(tmp_path / "trace")) == str(path)
+    reduced, breakdown = harness.reduce_trace(str(path), [0])
+    assert reduced.idle_share == pytest.approx(1 - 11.5 / 20)
+    assert [n for n, _ in breakdown["idle_gaps"]] == [
+        "gwas.write", "gwas.extract", "gwas.refine", "gwas.fence"]
+    assert breakdown["device_ops"] == [list(x) for x in reduced.device_ops]
+    assert harness.trace_file(str(tmp_path / "none")) is None
+    empty = _write(tmp_path / "empty.xplane.pb", 'planes { id: 1 name: "/host:CPU" }')
+    assert harness.reduce_trace(str(empty), [0]) == (None, None)
+
+
+def test_a_snapshot_carries_the_programs_counters_and_span_totals():
+    from repro.api.metrics import CellTiming, ScanMetrics
+
+    m = ScanMetrics()
+    m.record(CellTiming(batch_index=0, block_index=0, n_markers=8, n_traits=4, wall_s=0.5,
+                        refine_launches=3, hits=2))
+    m.fold_span("wait_input", 0.25)
+    m.fold_span("wait_input", 0.5)
+    snap = harness.scan_snapshot(m)
+    assert snap["counters"]["refine_launches"] == 3 and snap["counters"]["hits"] == 2
+    assert snap["spans"]["wait_input"] == (0.75, 2)
+    assert (snap["markers"], snap["cells"]) == (8, 1)
 
 
 # --------------------------------------------------------------- the readers
@@ -183,7 +211,7 @@ def _run(tmp_path, monkeypatch, text=XSPACE, traced=True):
     return harness.Run(cell=cell, spans=harness.Spans(), compiles=None, started=0.0,
                        window=(0.0, 20 * US), window_cells=[(8192, 20480)] * 2,
                        scan_before={"markers": 0}, scan_after={"markers": 16384},
-                       trace=reduced)
+                       trace=reduced, trace_path=str(path))
 
 
 READERS = {
@@ -220,5 +248,8 @@ def test_each_reader_reads_nothing_without_the_programs_names(tmp_path, monkeypa
 
 def test_a_stale_trace_is_not_read(tmp_path, monkeypatch):
     run = _run(tmp_path, monkeypatch)
-    run.window = (0.0, 5.0)       # a window the file's bench.window does not match
+    assert ts.of(run) is not None
+    # a run whose profiler wrote no file reads none, not another run's file
+    # left in the temporary directory
+    run.trace_path = None
     assert ts.of(run) is None

@@ -30,8 +30,8 @@ def test_idle_share_and_breakdown_order():
     assert red.idle_share == pytest.approx(0.62)
     assert [n for n, _ in red.device_ops] == ["gwas_dot", "fusion.1"]
     assert red.device_ops[0][1] == pytest.approx(330e-9)   # op time, overlaps not merged
-    assert [n for n, _ in red.idle_gaps] == ["bench.write", "bench.pull"]
-    assert [s for _, s in red.idle_gaps] == pytest.approx([400e-9, 220e-9])
+    # the idle gaps between the busy intervals (trace_scopes labels them)
+    assert tr.gaps(tr.union(_events().ops[0], 0, 1000), 0, 1000) == [(100, 320), (600, 1000)]
 
 
 def test_devices_are_averaged_and_labelled():
@@ -39,7 +39,18 @@ def test_devices_are_averaged_and_labelled():
     ev.ops[1] = [(0, 1000, "gwas_dot")]
     red = tr.reduce(ev, devices=[0, 1])
     assert red.mean_busy_s == pytest.approx(690e-9)
-    assert red.idle_gaps[0][0] == "TPU:0 bench.write"
+    assert dict(red.device_ops)["gwas_dot"] == pytest.approx(330e-9 + 1000e-9)  # summed
+    # each device's gaps are labelled by its slot's spans, under its name
+    import trace_scopes as ts
+
+    spans = [ts.Span(0, 1000, "bench.window", (1, 0)),
+             ts.Span(100, 320, "gwas.wait_input", (1, 1), {"slot": "dev0"}),
+             ts.Span(600, 1000, "gwas.extract", (1, 1), {"slot": "dev0"}),
+             ts.Span(0, 1000, "gwas.fence", (1, 2), {"slot": "dev1"})]
+    ops = {d: [ts.Op(s, e, n, None) for s, e, n in ev.ops[d]] for d in (0, 1)}
+    assert ts.gap_labels(ts.Scoped(ops=ops, spans=spans), [0, 1]) == [
+        ("TPU:0 gwas.extract", pytest.approx(400e-9)),
+        ("TPU:0 gwas.wait_input", pytest.approx(220e-9))]
 
 
 def test_nothing_to_read_gives_none():
@@ -75,6 +86,8 @@ planes {
 def test_load_reads_device_ops_and_bench_spans(tmp_path):
     from jax.profiler import ProfileData
 
+    import trace_scopes
+
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(ProfileData.text_proto_to_serialized_xspace(XSPACE))
     ev = tr.load(str(path))
@@ -83,8 +96,10 @@ def test_load_reads_device_ops_and_bench_spans(tmp_path):
     red = tr.reduce(ev)
     assert red.busy_s[0] == pytest.approx(3e-6)
     assert red.idle_share == pytest.approx(0.7)
-    assert red.idle_gaps[0] == ("no bench span", pytest.approx(4e-6))
-    assert red.idle_gaps[1] == ("bench.write", pytest.approx(3e-6))
+    # no program span in this trace: the breakdown's gaps fall back to bench spans
+    labels = trace_scopes.gap_labels(trace_scopes.load(str(path)), [0])
+    assert labels[0] == ("no span", pytest.approx(4e-6))
+    assert labels[1] == ("bench.write", pytest.approx(3e-6))
 
 
 def test_a_recorded_host_trace_yields_its_spans(tmp_path):

@@ -1,13 +1,12 @@
 """From a profiler trace (``.xplane.pb``) to device busy time, idle share
-and a breakdown.
+and the device operations that took the most time.
 
 Busy time on a device is the union of the intervals in which an operation
 of its ``XLA Ops`` line ran, clipped to the traced window; the window is
 the benchmark's own ``bench.window`` host span, on the same clock.  The
-idle share is ``1 - busy / window``.  The breakdown lists the device
-operations that took the most time, and the longest idle gaps, each
-labelled by the benchmark host span that overlaps it most (what the host
-was doing while the chip waited).
+idle share is ``1 - busy / window``.  The idle gaps between those
+intervals (``gaps``) are labelled by the program's own spans in
+``trace_scopes.gap_labels``.
 """
 from __future__ import annotations
 
@@ -66,22 +65,11 @@ def gaps(busy: list[tuple[float, float]], lo: float, hi: float) -> list[tuple[fl
     return [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
 
 
-def label(gap: tuple[float, float], spans) -> str:
-    """The host span covering most of ``gap`` (the window span excluded)."""
-    cover: dict[str, float] = defaultdict(float)
-    for s, e, name in spans:
-        if name != WINDOW_SPAN:
-            cover[name] += max(0.0, min(e, gap[1]) - max(s, gap[0]))
-    best = max(cover.items(), key=lambda kv: kv[1], default=("", 0.0))
-    return best[0] if best[1] > 0 else "no bench span"
-
-
 @dataclass
 class Reduced:
     window_s: float
     busy_s: dict[int, float]               # per device
     device_ops: list[tuple[str, float]]    # name, seconds (summed over devices)
-    idle_gaps: list[tuple[str, float]]     # label, seconds
 
     @property
     def mean_busy_s(self) -> float:
@@ -99,17 +87,11 @@ def reduce(ev: TraceEvents, devices: list[int] | None = None) -> Reduced | None:
     if not windows or not devices or not any(ev.ops.get(d) for d in devices):
         return None
     lo, hi = windows[0]
-    busy, per_op, idle = {}, defaultdict(float), []
+    busy, per_op = {}, defaultdict(float)
     for d in devices:
         ops = ev.ops.get(d, [])
-        merged = union(ops, lo, hi)
-        busy[d] = sum(e - s for s, e in merged) * 1e-9
+        busy[d] = sum(e - s for s, e in union(ops, lo, hi)) * 1e-9
         for s, e, name in ops:
             per_op[name] += max(0.0, min(e, hi) - max(s, lo)) * 1e-9
-        prefix = f"TPU:{d} " if len(devices) > 1 else ""
-        idle += [(prefix + label(g, ev.spans), (g[1] - g[0]) * 1e-9)
-                 for g in gaps(merged, lo, hi)]
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]
-    top_gaps = sorted(idle, key=lambda kv: -kv[1])[:TOP]
-    return Reduced(window_s=(hi - lo) * 1e-9, busy_s=busy, device_ops=top_ops,
-                   idle_gaps=top_gaps)
+    return Reduced(window_s=(hi - lo) * 1e-9, busy_s=busy, device_ops=top_ops)

@@ -16,17 +16,15 @@ labelled by the innermost span covering most of it: on one chip the spans
 of the thread that holds ``bench.window``, on several the spans of the slot
 computing on that device, else a ``bench.*`` span of the window's thread.
 
-This reads the trace file itself, beside ``trace_reduce`` (whose reduction
-it leaves as it is).  A metric reader finds its run's file with ``of(run)``.
+This reads the trace file itself, beside ``trace_reduce`` (whose busy
+time and device ops it leaves as they are); the result line's
+``breakdown.idle_gaps`` is ``gap_labels``.  A metric reader reads its
+run's file (``run.trace_path``) with ``of(run)``.
 """
 from __future__ import annotations
 
 import bisect
-import glob
-import math
-import os
 import re
-import tempfile
 from dataclasses import dataclass, field
 
 import trace_reduce
@@ -307,10 +305,11 @@ def gap_labels(tr: Scoped, devices: list[int]) -> list[tuple[str, float]]:
             own = [s for s in tr.spans if s.line == window_line and s.name.startswith(GWAS)]
             prefix = ""
         busy = trace_reduce.union([(o.start, o.end) for o in tr.ops.get(d, [])], lo, hi)
-        for g in trace_reduce.gaps(busy, lo, hi):
-            label = _innermost(g, own) or _innermost(g, bench) or "no span"
-            gaps.append((prefix + label, (g[1] - g[0]) * 1e-9))
-    return sorted(gaps, key=lambda kv: -kv[1])[:TOP]
+        gaps += [((g[1] - g[0]) * 1e-9, g, prefix, own) for g in trace_reduce.gaps(busy, lo, hi)]
+    # Only the longest are labelled: a window holds tens of thousands of gaps.
+    longest = sorted(gaps, key=lambda x: -x[0])[:TOP]
+    return [(prefix + (_innermost(g, own) or _innermost(g, bench) or "no span"), seconds)
+            for seconds, g, prefix, own in longest]
 
 
 # ------------------------------------------------------- from a metric reader
@@ -318,34 +317,21 @@ def gap_labels(tr: Scoped, devices: list[int]) -> list[tuple[str, float]]:
 _LOADED: dict[str, Scoped] = {}
 
 
-def trace_file(run) -> str | None:
-    """The ``.xplane.pb`` of ``run``: the newest under the benchmark's work
-    directories in the temporary directory, if its window span lasts as
-    long as the run's window (so a stale file is never read)."""
-    if run.trace is None:
-        return None
-    pattern = os.path.join(tempfile.gettempdir(), "gwasbench_*", "trace", "**",
-                           "*.xplane.pb")
-    found = sorted(glob.glob(pattern, recursive=True), key=os.path.getmtime)
-    if not found:
-        return None
-    path = found[-1]
+def load_once(path: str) -> Scoped:
+    """``load(path)``, read once per process (the harness and every reader
+    of a traced run share it)."""
     if path not in _LOADED:
         _LOADED[path] = load(path)
-    window = _LOADED[path].window()
-    if window is None or not math.isclose((window[1] - window[0]) * 1e-9, run.window_s,
-                                          rel_tol=0.01, abs_tol=0.01):
-        return None
-    return path
+    return _LOADED[path]
 
 
 def of(run) -> Scoped | None:
-    """The run's trace with the program's names, or None when the run was
-    not traced or the program opened no ``gwas.*`` span in its window."""
-    path = trace_file(run)
-    if path is None:
+    """The run's trace (``run.trace_path``) with the program's names, or
+    None when the run was not traced or the program opened no ``gwas.*``
+    span in its window."""
+    if run.trace is None or run.trace_path is None:
         return None
-    tr = _LOADED[path]
+    tr = load_once(run.trace_path)
     lo, hi = tr.window()
     if not any(s.name.startswith(GWAS) and s.start >= lo and s.end <= hi for s in tr.spans):
         return None
